@@ -1,8 +1,9 @@
-"""Kernel-layer throughput: us/call for the profiling + GEMM + attention
-paths. Pallas kernels execute in interpret mode on this CPU container (the
-TPU target cannot run here), so the numbers below time (a) the pure-jnp
-reference paths that the kernels are validated against and (b) the host-side
-numpy profiler — i.e. the throughput of what actually runs in this container.
+"""Kernel-layer throughput: us/call for the pure-jnp reference paths that the
+toggle-count, WS-matmul and attention kernels are validated against, and for
+the host-side numpy profiler. No Pallas kernel runs here, in interpret mode
+or otherwise; the activity-profiling kernels are timed through the engines
+in ``bench_activity_profile`` and ``bench_network_profile``. Every number is
+a wall-clock time on whatever backend JAX selected.
 """
 
 from __future__ import annotations

@@ -11,10 +11,12 @@ device round-trip per layer. The batched path hands the same jobs to
 `run_profile_batch`: a couple of fused device programs, operand synthesis
 overlapped with device work.
 
-Wall-clock is measured in a FRESH SUBPROCESS per side (full mode), because
-per-shape recompiles are the serial path's real per-workload cost and an
-in-process A/B is biased by whichever side warms the JIT/LLVM first. Smoke
-mode times in-process (no subprocesses, no 3x assertion). The module fails
+Cold wall-clock (full mode) is timed in this process, one side at a time,
+after ``jax.clear_caches()`` with the persistent compile cache off, because
+per-shape recompiles are the serial path's real per-workload cost. Timing
+stays in one process so that a TPU host's chip is never wanted by a child
+while this process holds it. Smoke mode times one warm in-process batched
+run (no 3x assertion). The module fails
 loudly unless the batched toggle counts are bit-exact against the per-GEMM
 engine on every job and against the numpy counts oracle
 (`profile_gemm_toggles_ref`) on the whole workload (full mode; smoke checks
@@ -25,12 +27,12 @@ Acceptance target: >= 3x lower cold wall-clock for the batched pipeline.
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
 import time
 
+import jax
+
+from repro.compile_cache import persistent_cache_disabled
 from repro.configs.registry import get_arch
 from repro.core.pipeline import run_profile_batch
 from repro.core.switching import clear_profile_cache, profile_gemm
@@ -82,41 +84,19 @@ def _run_serial(jobs):
     return out
 
 
-_CHILD = """
-import json, sys, time
-from benchmarks.bench_network_profile import _jobs, _run_serial
-from repro.core.pipeline import run_profile_batch
-
-mode = sys.argv[1]
-jobs = _jobs(False)
-t0 = time.perf_counter()
-if mode == "serial":
-    _run_serial(jobs)
-else:
-    run_profile_batch(jobs, use_cache=False)
-print(json.dumps({"seconds": time.perf_counter() - t0}))
-"""
-
-
-def _timed_subprocess(mode: str) -> float:
-    """Cold wall-clock of one side in a fresh interpreter (imports excluded)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.dirname(os.path.dirname(__file__)),) + tuple(sys.path)
-        if p
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", _CHILD, mode],
-        capture_output=True,
-        text=True,
-        env=env,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"{mode} timing child failed (exit {proc.returncode}):\n"
-            f"{proc.stderr[-2000:]}"
-        )
-    return float(json.loads(proc.stdout.strip().splitlines()[-1])["seconds"])
+def _timed_cold(mode: str) -> float:
+    """Cold wall-clock of one side: compiled programs and profiles dropped
+    first, persistent compile cache off (operand synthesis included)."""
+    jax.clear_caches()
+    clear_profile_cache()
+    jobs = _jobs(False)
+    with persistent_cache_disabled():
+        t0 = time.perf_counter()
+        if mode == "serial":
+            _run_serial(jobs)
+        else:
+            run_profile_batch(jobs, use_cache=False)
+        return time.perf_counter() - t0
 
 
 def _counts(profile):
@@ -148,16 +128,13 @@ def _oracle_check(jobs, profiles, indices):
 
 def run(smoke: bool = False) -> list[dict]:
     if not smoke:
-        # --- cold wall-clock FIRST, one fresh interpreter per side ----------
-        # Before anything in this process warms the OS caches for LLVM/XLA
-        # (which would deflate the serial side's true per-shape compile
-        # cost). Interleaved samples + medians: wall-clock on shared boxes
-        # is noisy (compile time swings with CPU boost state), and the first
-        # child of a session pays extra OS-cache warmup.
+        # --- cold wall-clock, interleaved samples + medians ----------------
+        # Wall-clock on shared boxes is noisy (compile time swings with CPU
+        # boost state), and the first sample pays extra OS-cache warmup.
         serial_s, batch_s = [], []
         for _ in range(3):
-            serial_s.append(_timed_subprocess("serial"))
-            batch_s.append(_timed_subprocess("batched"))
+            serial_s.append(_timed_cold("serial"))
+            batch_s.append(_timed_cold("batched"))
 
     # --- bit-exactness: batched vs per-GEMM engine vs numpy oracle ----------
     clear_profile_cache()

@@ -43,6 +43,7 @@ from benchmarks import (
     bench_serving,
     bench_table1_layers,
 )
+from repro.compile_cache import configure_compile_cache
 
 MODULES = [
     ("aspect_sweep", bench_aspect_sweep),
@@ -69,6 +70,7 @@ def main(argv: list[str] | None = None) -> None:
         "--json", metavar="PATH", default=None, help="also write results as JSON"
     )
     args = parser.parse_args(argv)
+    configure_compile_cache()
 
     print("name,us_per_call,derived")
     failed = False

@@ -36,8 +36,11 @@ import sys
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core.design_space import DesignSpace, evaluate_design_space
 from repro.core.workloads import RESNET50_TABLE1, measured_design_activities
+
+configure_compile_cache()
 
 ap = argparse.ArgumentParser(description=__doc__.split("\n", 1)[0])
 ap.add_argument("--store", default=None, metavar="DIR",

@@ -12,6 +12,9 @@ from repro.core import (
     optimal_aspect_power,
     profile_gemm,
 )
+from repro.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 # 1. the paper's array: 32x32 PEs, int16 operands, 37-bit partial sums
 geom = SystolicArrayGeometry.paper_32x32()

@@ -10,6 +10,7 @@ shape class.
     PYTHONPATH=src python examples/sa_power_llm.py
 """
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs.registry import ARCH_IDS, get_arch
 from repro.core.energy import compare_sym_asym
 from repro.core.floorplan import (
@@ -20,6 +21,8 @@ from repro.core.floorplan import (
 )
 from repro.core.switching import combine_profiles, profile_gemms
 from repro.core.workloads import gemm_job, gemms_for_arch
+
+configure_compile_cache()
 
 ROWS = COLS = 128
 BITS = 8

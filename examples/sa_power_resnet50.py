@@ -7,6 +7,7 @@ then the same savings re-derived from the segment-level layout engine
     PYTHONPATH=src python examples/sa_power_resnet50.py
 """
 
+from repro.compile_cache import configure_compile_cache
 from repro.core.energy import (
     average_comparison,
     calibration_split_arr,
@@ -17,6 +18,8 @@ from repro.core.switching import combine_profiles, profile_cache_info
 from repro.core.systolic import schedule_gemm
 from repro.core.workloads import RESNET50_TABLE1, conv_to_gemm, profile_network
 from repro.layout import LayoutPowerConfig, evaluate_layout_space, segment_bus_power
+
+configure_compile_cache()
 
 geom = SystolicArrayGeometry.paper_32x32()
 

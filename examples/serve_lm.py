@@ -9,9 +9,12 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs.registry import get_arch
 from repro.launch.serve import generate
 from repro.models import model
+
+configure_compile_cache()
 
 for arch in ("mixtral_8x7b", "xlstm_1p3b"):
     cfg = get_arch(arch).reduced()

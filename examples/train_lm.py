@@ -7,6 +7,7 @@ hundred steps on CPU with checkpointing + crash-recovery demonstrated live.
 import argparse
 import tempfile
 
+from repro.compile_cache import configure_compile_cache
 from repro.launch.train import build
 
 
@@ -15,6 +16,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="qwen3_8b")
     args = ap.parse_args()
+    configure_compile_cache()
 
     with tempfile.TemporaryDirectory() as d:
         # phase 1: train, then simulate a crash at 60% of the run

@@ -73,7 +73,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
-import warnings
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Sequence
 
@@ -99,7 +98,6 @@ from repro.runtime.resilience import (
     ContractViolationError,
     DeviceDispatchError,
     FailureReport,
-    ProfileDegradationWarning,
     ProfileError,
     RetryPolicy,
     call_with_retry,
@@ -542,6 +540,7 @@ def run_profile_batch(
     timeout_s: float | None = None,
     retry: RetryPolicy | None = None,
     health: HealthMonitor | None = None,
+    devices: Sequence | None = None,
 ) -> tuple[list[ActivityProfile | None], BatchStats]:
     """Profile every job; returns (profiles in input order, scheduler stats).
 
@@ -563,6 +562,8 @@ def run_profile_batch(
     (a ``HealthMonitor``, created internally when not passed) and its task
     slice resubmitted once to a surviving device.  ``retry`` is the
     ``RetryPolicy`` for transient faults inside recovery ladders.
+    ``devices`` are the devices each bucket's task axis is sharded over
+    (default ``jax.local_devices()``).
     ``BatchStats.failure_report`` enumerates every failure with its typed
     cause and the recovery action taken.
     """
@@ -667,22 +668,11 @@ def run_profile_batch(
     # genuinely in parallel — on TPU pods, or on CPU hosts running with
     # ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. The serial
     # per-GEMM path cannot do this: it blocks on every layer's result.
-    try:
-        import jax
+    # A backend that fails to initialise raises here: the pipeline never
+    # runs on a placeholder device.
+    import jax
 
-        devices = jax.local_devices()
-    except (ImportError, RuntimeError) as exc:  # pragma: no cover - no jax
-        # Narrow on purpose: ImportError = jax genuinely absent,
-        # RuntimeError = jax present but backend init failed.  Anything else
-        # is a real bug that must NOT masquerade as "jax unavailable".
-        warnings.warn(
-            f"batched pipeline: jax unavailable for device dispatch "
-            f"({type(exc).__name__}: {exc}); falling back to a single "
-            "anonymous device slot",
-            ProfileDegradationWarning,
-            stacklevel=2,
-        )
-        devices = [None]
+    devices = list(devices) if devices is not None else jax.local_devices()
 
     if health is None:
         health = HealthMonitor(range(len(devices)))
@@ -803,13 +793,12 @@ def run_profile_batch(
 
     prefetch_pool = ThreadPoolExecutor(max_workers=1)
     try:
-        if devices != [None]:
-            # Pay the one-time XLA/LLVM backend spin-up concurrently with
-            # the first bucket's operand synthesis instead of inside its
-            # (timed) first compile.
-            import jax.numpy as jnp
+        # Pay the one-time XLA/LLVM backend spin-up concurrently with the
+        # first bucket's operand synthesis instead of inside its (timed)
+        # first compile.
+        import jax.numpy as jnp
 
-            executor.submit(jax.jit(lambda x: x + 1), jnp.zeros(8, jnp.int32))
+        executor.submit(jax.jit(lambda x: x + 1), jnp.zeros(8, jnp.int32))
 
         # Materialize lazy operands a bounded window ahead on a side thread
         # (numpy synthesis releases the GIL), in the same order the group

@@ -1003,12 +1003,9 @@ def _resolve_store(sweep: SweepConfig) -> ContentStore | None:
 
 
 def _local_devices() -> list:
-    try:
-        import jax
+    import jax
 
-        return list(jax.local_devices())
-    except Exception:
-        return [None]
+    return list(jax.local_devices())
 
 
 def _poisoned(out: dict, rung: str, index: int) -> dict:

@@ -34,6 +34,7 @@ __all__ = [
     "gemm_job",
     "profile_network",
     "measured_design_activities",
+    "design_gemm_jobs",
     "measured_design_gemm_activities",
     "gemm_profile_seed",
     "measured_design_lane_activities",
@@ -442,7 +443,7 @@ def gemm_profile_seed(
     return int.from_bytes(hashlib.sha256(key).digest()[:4], "little")
 
 
-def measured_design_gemm_activities(
+def design_gemm_jobs(
     grid,
     gemms: Sequence[Gemm],
     *,
@@ -450,24 +451,19 @@ def measured_design_gemm_activities(
     seeds: Sequence[int] | None = None,
     clip: tuple[int, int, int] | None = (128, 512, 256),
     profile_cols: int | None = None,
-    backend: str | None = None,
-    use_cache: bool = True,
-    return_stats: bool = False,
 ):
-    """Measured (G, P) activity arrays for a GEMM job set — the serving
-    adapter mirroring ``measured_design_activities``.
+    """The profiling jobs behind ``measured_design_gemm_activities``.
 
-    One ``gemm_job`` per activity class per GEMM (same class invariance
-    arguments: WS classes are (rows, b_h, b_v_data), OS classes the
-    geometry-free (b_h, b_v_data)) feeds every point of the grid.
-    ``clip`` bounds the profiled slice of LLM-sized GEMMs (toggle RATES
-    converge long before full model dims; the J/op objective still prices
-    utilization/spill/trunk from the FULL dims).  Seeds default to the
-    content-keyed ``gemm_profile_seed`` so shape classes shared across
-    models and traffic mixes dedup in the profile cache.
+    One ``gemm_job`` per activity class per unique operand class (same
+    class invariance arguments as ``measured_design_activities``: WS classes
+    are (rows, b_h, b_v_data), OS classes the geometry-free (b_h,
+    b_v_data)).  Seeds default to the content-keyed ``gemm_profile_seed``
+    so shape classes shared across models and traffic mixes dedup in the
+    profile cache.  Returns ``(jobs, gemm_uniq, point_class)``: jobs are
+    class-major over the unique operand classes, ``gemm_uniq`` (G,) maps
+    each GEMM to its operand class and ``point_class`` (P,) each grid point
+    to its activity class.
     """
-    from repro.core.pipeline import run_profile_batch
-
     gemms = list(gemms)
     if not gemms:
         raise ValueError("no gemms")
@@ -517,13 +513,44 @@ def measured_design_gemm_activities(
         for cls in classes
         for g, density, seed in uniq_items
     ]
+    return jobs, gemm_uniq, point_class
+
+
+def measured_design_gemm_activities(
+    grid,
+    gemms: Sequence[Gemm],
+    *,
+    densities: Sequence[float | None] | None = None,
+    seeds: Sequence[int] | None = None,
+    clip: tuple[int, int, int] | None = (128, 512, 256),
+    profile_cols: int | None = None,
+    backend: str | None = None,
+    use_cache: bool = True,
+    return_stats: bool = False,
+):
+    """Measured (G, P) activity arrays for a GEMM job set — the serving
+    adapter mirroring ``measured_design_activities``.
+
+    The jobs of ``design_gemm_jobs`` (one per activity class per unique
+    operand class) feed every point of the grid. ``clip`` bounds the
+    profiled slice of LLM-sized GEMMs (toggle RATES converge long before
+    full model dims; the J/op objective still prices utilization/spill/
+    trunk from the FULL dims).
+    """
+    from repro.core.pipeline import run_profile_batch
+
+    jobs, gemm_uniq, point_class = design_gemm_jobs(
+        grid, gemms, densities=densities, seeds=seeds, clip=clip,
+        profile_cols=profile_cols,
+    )
     profiles, stats = run_profile_batch(jobs, backend=backend, use_cache=use_cache)
-    n_u = len(uniq_items)
+    n_u = int(gemm_uniq.max()) + 1
+    n_c = len(jobs) // n_u
     class_a_h = np.asarray(
-        [[profiles[c * n_u + u].a_h for c in range(len(classes))] for u in range(n_u)]
+        [[profiles[c * n_u + u].a_h for c in range(n_c)] for u in range(n_u)]
     )
     class_a_v = np.asarray(
-        [[profiles[c * n_u + u].a_v for c in range(len(classes))] for u in range(n_u)]
+        [[profiles[c * n_u + u].a_v for c in range(n_c)] for u in range(n_u)]
     )
     a_h = class_a_h[gemm_uniq][:, point_class]
     a_v = class_a_v[gemm_uniq][:, point_class]

@@ -1,36 +1,42 @@
-"""Fused Pallas TPU kernel: single-pass WS switching-activity profiling.
+"""Fused Pallas TPU kernels: single-pass WS/OS switching-activity profiling.
 
 Replaces the host-side pipeline ``vertical_partial_sums`` (a materialized
-(T, R, C) int64 cumsum) + XOR-popcount with ONE kernel that, per
-``(weight_tile, t_block)`` grid cell:
+(T, R, C) int64 cumsum) + XOR-popcount with kernels that, per grid cell:
 
-  1. streams a ``(block_t, R)`` activation block through the resident
-     ``(R, C)`` weight tile,
-  2. forms the running partial-sum cumsum down R **in-kernel** — carried as
-     lo/hi int32 planes so the paper's 37-bit accumulations stay exact
-     without 64-bit arithmetic (the VPU has none),
-  3. XORs each time step against its predecessor (the cross-block
-     predecessor lives in VMEM scratch, persistent across the sequential
-     grid), popcounts under the bus-width mask, and
-  4. accumulates toggle totals for BOTH the horizontal input buses and the
-     vertical partial-sum buses.
+  1. stream a block of activation steps through the resident ``(R, C)``
+     weight tile,
+  2. walk the reduction rows in a statically unrolled loop, carrying the
+     running partial sum of every (step, column) as lo/hi int32 planes so
+     the paper's 37-bit accumulations stay exact without 64-bit arithmetic
+     (the VPU has none),
+  3. XOR each time step against its predecessor, popcount under the
+     bus-width mask, and
+  4. reduce the counts to one int32 total per grid cell.
 
 The (T, R, C) partial-sum tensor therefore never exists anywhere — not in
-host memory, not in HBM; each element is produced, toggled against, and
-discarded inside one VMEM-resident block.
+host memory, not in HBM, not in VMEM: each (T_block, C) plane of row r is
+produced, toggled against, and overwritten by row r + 1.
+
+TPU layout rules shape the code. Reduction row r is read as a static lane
+slice of the activation block and a static sublane slice of the weight tile
+(Mosaic lowers no dynamic lane slicing). Per-cell totals leave the kernel
+lane-dense: a grid axis of up to ``LANE`` consecutive cells shares one
+(1, 1, n) output block and cell j writes lane j (a rank-1 or (1, 1) block
+would break the (8, 128) tiling rule).
 
 Exact 64-bit partial sums from int32 lanes
 ------------------------------------------
-For int16 operands every product fits int32. Split ``p = p_hi * 2^16 + p_lo``
-with ``p_lo = p & 0xffff`` (in [0, 2^16)) and ``p_hi = p >> 16`` (arithmetic,
-in [-2^15, 2^15)). Both planes cumsum exactly in int32 for any realistic R
-(R < 2^15), and ``S = Hc * 2^16 + Lc`` is reconstructed mod 2^64 as
-``(s_lo, s_hi)`` int32 planes with one unsigned-compare carry. Bus toggles on
-a ``bits``-wide two's-complement bus are then popcounts of the XORed planes
-under a static (lo_mask, hi_mask) split — exact for bits in [1, 64].
+Every int16 x int16 product fits int32. The running sum adds each product
+to the lo plane (wrapping mod 2^32) and carries into the hi plane on
+unsigned overflow, plus the product's sign extension — exact mod 2^64. A
+bus of ``bits`` <= 32 sees only the lo plane, so those kernels skip the hi
+plane. Bus toggles on a ``bits``-wide two's-complement bus are popcounts of
+the XORed planes under a static (lo_mask, hi_mask) split — exact for bits
+in [1, 64].
 
-The same jnp helpers below are shared by the jitted XLA fallback in ops.py
-(used when no TPU is attached), so both engines are one algorithm.
+The same jnp helpers below are shared by the jitted XLA renderings in
+ops.py and batch.py (used when no TPU is attached), so both engines are one
+algorithm.
 
 Output-stationary profiling needs no partial-sum machinery at all — both OS
 buses carry raw operand streams — so its kernels are the lighter
@@ -49,12 +55,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bitops import popcount_u32 as _popcount_u32
 
-# Upper bound on block_t * rows * cols (elements of one in-flight plane).
-# Keeps every temporary comfortably inside VMEM and bounds each grid cell's
-# toggle partial at ~2^26 * 96 bits, far below int32 overflow.
+# Upper bound on block_t * rows * cols: bounds each grid cell's toggle count
+# at ~2^20 * 128 bits, far below int32 overflow.
 DEFAULT_BLOCK_BUDGET = 1 << 20
+# Upper bound on one live (block_t, cols) int32 plane, lanes padded to a
+# multiple of LANE: 256 KiB. The WS kernels keep about a dozen such planes
+# live, well inside the TPU's default scoped VMEM.
+PLANE_VMEM_ELEMS = 1 << 16
 MAX_BLOCK_T = 512
 MIN_BLOCK_T = 8
+LANE = 128  # TPU vector lane count
+# Stream lanes per grid cell of the per-GEMM operand-stream kernel.
+STREAM_LANE_BLOCK = 8 * LANE
+# Tasks per pallas_call of the task kernel: its three prefetched (P,) int32
+# metadata arrays live in SMEM (1 MiB on v5e).
+MAX_CALL_TASKS = 1 << 14
 
 __all__ = [
     "DEFAULT_BLOCK_BUDGET",
@@ -71,8 +86,10 @@ __all__ = [
 
 
 def choose_block_t(rows: int, cols: int, budget: int = DEFAULT_BLOCK_BUDGET) -> int:
-    """Time-block size: as many steps as the element budget allows, 8-aligned."""
-    bt = budget // max(rows * cols, 1)
+    """Time-block size: as many steps as the element and VMEM budgets allow,
+    8-aligned."""
+    lanes = -(-max(cols, 1) // LANE) * LANE
+    bt = min(budget // max(rows * cols, 1), PLANE_VMEM_ELEMS // lanes)
     bt = max(MIN_BLOCK_T, min(MAX_BLOCK_T, bt))
     return bt - (bt % MIN_BLOCK_T)
 
@@ -140,10 +157,30 @@ def value32_toggles(cur: jnp.ndarray, prev: jnp.ndarray, bits: int) -> jnp.ndarr
     return base + sign_flip * jnp.int32(bits - 32)
 
 
+def _add_row(run_lo, run_hi, prod, bits: int):
+    """Add one reduction row's int32 products to the running lo/hi planes
+    (exact mod 2^64; the hi plane is skipped for buses <= 32 bits, which
+    see only the mod-2^32 lo plane)."""
+    new_lo = run_lo + prod
+    if bits <= 32:
+        return new_lo, run_hi
+    carry = (new_lo.astype(jnp.uint32) < run_lo.astype(jnp.uint32)).astype(jnp.int32)
+    return new_lo, run_hi + (prod >> jnp.int32(31)) + carry
+
+
+def _store_lane(o_ref, j, value) -> None:
+    """Write scalar ``value`` into lane ``j`` of a (1, 1, n) output block."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 2)
+    o_ref[...] = jnp.where(lane == j, value, o_ref[...])
+
+
+_CELL_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "rows", "cols", "k", "n", "b_h", "b_v", "block_t", "interpret",
+        "rows", "cols", "k", "b_h", "b_v", "block_t", "interpret",
     ),
 )
 def activity_profile_pallas(
@@ -153,7 +190,6 @@ def activity_profile_pallas(
     rows: int,
     cols: int,
     k: int,
-    n: int,
     b_h: int,
     b_v: int,
     block_t: int,
@@ -163,13 +199,20 @@ def activity_profile_pallas(
 
     ``a_pad`` is (T_pad, K_pad) int32 — T edge-padded (replicated last row:
     zero extra toggles), K zero-padded to a multiple of ``rows``. ``w_pad``
-    is (K_pad, N_pad) int32, zero-padded. ``k``/``n`` are the true (unpadded)
-    GEMM dims; edge tiles mask their padding lanes out of the counts, so
-    totals are bit-exact vs. the unpadded numpy oracle.
+    is (K_pad, N_pad) int32, zero-padded. ``k`` is the true (unpadded)
+    reduction depth: K-padding rows would repeat the previous row's
+    vertical count and are gated out; zero-padded K lanes and N columns
+    toggle nothing. Totals are bit-exact vs. the unpadded numpy oracle.
+
+    The operands are regrouped into (k_tiles, T_pad, rows) strips and
+    (tiles, rows, cols) weight tiles so every block spans whole minor
+    dimensions. Grid: (tile, t-block); the last partial-sum row of the
+    previous t-block rides in VMEM scratch.
 
     Returns per-grid-cell int32 partials ``(h_out, v_out)`` of shape
-    (num_tiles, num_t_blocks); the caller reduces them in int64. Each cell's
-    count is bounded by block_t*rows*cols*(64+b_h) < 2^31 via choose_block_t.
+    (num_tiles, 1, num_t_blocks); the caller reduces them in int64. Each
+    cell's count is bounded by block_t*rows*cols*(64+b_h) < 2^31 via
+    choose_block_t.
     """
     t_pad, k_pad = a_pad.shape
     n_pad = w_pad.shape[1]
@@ -182,67 +225,69 @@ def activity_profile_pallas(
     n_tiles = n_pad // cols
     num_tiles = k_tiles * n_tiles
     num_tb = t_pad // block_t
+    a_strips = a_pad.reshape(t_pad, k_tiles, rows).transpose(1, 0, 2)
+    w_tiles = (
+        w_pad.reshape(k_tiles, rows, n_tiles, cols)
+        .transpose(0, 2, 1, 3)
+        .reshape(num_tiles, rows, cols)
+    )
 
     def kernel(a_ref, w_ref, h_ref, v_ref, prev_lo, prev_hi, prev_a):
         p = pl.program_id(0)
         j = pl.program_id(1)
-        a = a_ref[...]  # (block_t, rows)
-        w = w_ref[...]  # (rows, cols)
-        s_lo, s_hi = partial_sum_planes(a, w)
+        later = j > 0  # the first t-block has no predecessor transition
+        valid_r = jnp.minimum(rows, k - (p // n_tiles) * rows)
 
-        # First t-block of a tile: seed the carry with t=0 so the (nonexistent)
-        # transition into the first time step contributes zero toggles.
-        @pl.when(j == 0)
-        def _():
-            prev_lo[...] = s_lo[0]
-            prev_hi[...] = s_hi[0]
-            prev_a[...] = a[:1]
-
-        lag_lo = jnp.concatenate([prev_lo[...][None], s_lo[:-1]], axis=0)
-        lag_hi = jnp.concatenate([prev_hi[...][None], s_hi[:-1]], axis=0)
-        lag_a = jnp.concatenate([prev_a[...], a[:-1]], axis=0)
-
-        # Edge tiles: mask PEs beyond the true K/N extent out of the counts.
-        kt = p // n_tiles
-        nt = p % n_tiles
-        valid_r = jnp.minimum(rows, k - kt * rows)
-        valid_c = jnp.minimum(cols, n - nt * cols)
-        rid = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
-        cid = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        vmask = (rid < valid_r) & (cid < valid_c)
-        hmask = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) < valid_r
-
-        v_cnt = planes_toggles(s_lo, s_hi, lag_lo, lag_hi, b_v)
-        h_cnt = value32_toggles(a, lag_a, b_h)
-        v_ref[0, 0] = jnp.sum(jnp.where(vmask[None, :, :], v_cnt, 0))
-        h_ref[0, 0] = jnp.sum(jnp.where(hmask, h_cnt, 0))
-
-        prev_lo[...] = s_lo[-1]
-        prev_hi[...] = s_hi[-1]
+        a = a_ref[0]  # (block_t, rows)
+        h = jnp.sum(value32_toggles(a[1:], a[:-1], b_h))
+        h_edge = jnp.sum(value32_toggles(a[:1], prev_a[...], b_h))
         prev_a[...] = a[-1:]
 
+        run_lo = run_hi = jnp.zeros((block_t, cols), jnp.int32)
+        acc = jnp.zeros((block_t - 1, cols), jnp.int32)
+        edge = jnp.zeros((1, cols), jnp.int32)
+        for r in range(rows):
+            prod = a_ref[0, :, r : r + 1] * w_ref[0, r : r + 1, :]
+            run_lo, run_hi = _add_row(run_lo, run_hi, prod, b_v)
+            live = r < valid_r
+            acc += jnp.where(
+                live,
+                planes_toggles(run_lo[1:], run_hi[1:], run_lo[:-1], run_hi[:-1], b_v),
+                0,
+            )
+            edge += jnp.where(
+                live,
+                planes_toggles(
+                    run_lo[:1], run_hi[:1],
+                    prev_lo[r : r + 1, :], prev_hi[r : r + 1, :], b_v,
+                ),
+                0,
+            )
+            prev_lo[r : r + 1, :] = run_lo[-1:]
+            prev_hi[r : r + 1, :] = run_hi[-1:]
+        v = jnp.sum(acc) + jnp.where(later, jnp.sum(edge), 0)
+        _store_lane(h_ref, j, h + jnp.where(later, h_edge, 0))
+        _store_lane(v_ref, j, v)
+
+    out_spec = pl.BlockSpec((1, 1, num_tb), lambda p, j: (p, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((num_tiles, 1, num_tb), jnp.int32)
     return pl.pallas_call(
         kernel,
         grid=(num_tiles, num_tb),
         in_specs=[
-            pl.BlockSpec((block_t, rows), lambda p, j: (j, p // n_tiles)),
-            pl.BlockSpec((rows, cols), lambda p, j: (p // n_tiles, p % n_tiles)),
+            pl.BlockSpec((1, block_t, rows), lambda p, j: (p // n_tiles, j, 0)),
+            pl.BlockSpec((1, rows, cols), lambda p, j: (p, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda p, j: (p, j)),
-            pl.BlockSpec((1, 1), lambda p, j: (p, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((num_tiles, num_tb), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, num_tb), jnp.int32),
-        ],
+        out_specs=[out_spec, out_spec],
+        out_shape=[out_shape, out_shape],
         scratch_shapes=[
             pltpu.VMEM((rows, cols), jnp.int32),
             pltpu.VMEM((rows, cols), jnp.int32),
             pltpu.VMEM((1, rows), jnp.int32),
         ],
+        compiler_params=_CELL_SEMANTICS,
         interpret=interpret,
-    )(a_pad, w_pad)
+    )(a_strips, w_tiles)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_t", "interpret"))
@@ -259,37 +304,37 @@ def operand_stream_toggles_pallas(
     sequences with no cross-lane arithmetic — so its per-GEMM profile needs
     only this kernel: ``x_pad`` is (T_pad, L) int32, one stream per column,
     T edge-padded to a ``block_t`` multiple (replicated values toggle zero
-    bits).  One grid cell per time block; the previous block's last row is
-    carried in VMEM scratch so cross-block transitions count exactly once.
-    Returns (num_t_blocks, block_t) int32 partials reduced per TIME ROW,
-    not per block — each bounded by L * 64 regardless of ``block_t``
-    (< 2^31 for any L < 2^25, the ``MAX_FUSED_LANES`` contract), exactly
-    like the XLA h pass; the caller reduces in int64.
+    bits).  Grid: (lane block, time block); lanes beyond
+    ``STREAM_LANE_BLOCK`` are split into zero-padded blocks (constant lanes
+    toggle nothing), and the previous time block's last row is carried in
+    VMEM scratch so cross-block transitions count exactly once.  Returns
+    (num_lane_blocks, 1, num_t_blocks) int32 partials, each bounded by
+    block_t * STREAM_LANE_BLOCK * 64 < 2^31; the caller reduces in int64.
     """
     t_pad, lanes = x_pad.shape
     if t_pad % block_t:
         raise ValueError(f"padded stream length {t_pad} not a multiple of {block_t}")
     num_tb = t_pad // block_t
+    block_l = lanes if lanes <= STREAM_LANE_BLOCK else STREAM_LANE_BLOCK
+    x_pad = jnp.pad(x_pad, ((0, 0), (0, (-lanes) % block_l)))
+    num_lb = x_pad.shape[1] // block_l
 
     def kernel(x_ref, o_ref, prev_x):
-        j = pl.program_id(0)
-        x = x_ref[...]  # (block_t, lanes)
-
-        @pl.when(j == 0)
-        def _():
-            prev_x[...] = x[:1]
-
-        lag = jnp.concatenate([prev_x[...], x[:-1]], axis=0)
-        o_ref[0, :] = jnp.sum(value32_toggles(x, lag, bits), axis=1)
+        j = pl.program_id(1)
+        x = x_ref[...]  # (block_t, block_l)
+        inner = jnp.sum(value32_toggles(x[1:], x[:-1], bits))
+        edge = jnp.sum(value32_toggles(x[:1], prev_x[...], bits))
         prev_x[...] = x[-1:]
+        _store_lane(o_ref, j, inner + jnp.where(j > 0, edge, 0))
 
     return pl.pallas_call(
         kernel,
-        grid=(num_tb,),
-        in_specs=[pl.BlockSpec((block_t, lanes), lambda j: (j, 0))],
-        out_specs=pl.BlockSpec((1, block_t), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_tb, block_t), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, lanes), jnp.int32)],
+        grid=(num_lb, num_tb),
+        in_specs=[pl.BlockSpec((block_t, block_l), lambda i, j: (j, i))],
+        out_specs=pl.BlockSpec((1, 1, num_tb), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((num_lb, 1, num_tb), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, block_l), jnp.int32)],
+        compiler_params=_CELL_SEMANTICS,
         interpret=interpret,
     )(x_pad)
 
@@ -306,23 +351,33 @@ def stream_strips_toggles_pallas(
     The batch pipeline flattens OS operand streams (and WS horizontal
     streams) into independent (t_seg + 1, lanes) windows whose row 0 seeds
     the cross-window transition (see ``batch.segment_strips``); each grid
-    cell toggles one window.  Returns (S,) int32 totals, each bounded by
+    cell toggles one window, and ``LANE`` consecutive cells share one
+    lane-dense output row.  Returns (S,) int32 totals, each bounded by
     t_seg * lanes * 64 < 2^31 by the segment budget; callers reduce int64.
     """
     num_strips, t_seg1, lanes = strips.shape
+    groups = -(-num_strips // LANE)
 
     def kernel(s_ref, o_ref):
         s = s_ref[0]  # (t_seg + 1, lanes)
-        o_ref[0] = jnp.sum(value32_toggles(s[1:], s[:-1], bits))
+        _store_lane(o_ref, pl.program_id(1), jnp.sum(value32_toggles(s[1:], s[:-1], bits)))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(num_strips,),
-        in_specs=[pl.BlockSpec((1, t_seg1, lanes), lambda p: (p, 0, 0))],
-        out_specs=pl.BlockSpec((1,), lambda p: (p,)),
-        out_shape=jax.ShapeDtypeStruct((num_strips,), jnp.int32),
+        grid=(groups, LANE),
+        in_specs=[
+            # cells past the last strip re-read it; their lanes are dropped
+            pl.BlockSpec(
+                (1, t_seg1, lanes),
+                lambda g, j: (jnp.minimum(g * LANE + j, num_strips - 1), 0, 0),
+            )
+        ],
+        out_specs=pl.BlockSpec((1, 1, LANE), lambda g, j: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((groups, 1, LANE), jnp.int32),
+        compiler_params=_CELL_SEMANTICS,
         interpret=interpret,
     )(strips)
+    return out.reshape(-1)[:num_strips]
 
 
 @functools.partial(
@@ -349,12 +404,14 @@ def activity_profile_pallas_tasks(
     BlockSpec index maps can route each cell to its operands: ``strips`` is
     (S, t_seg + 1, rows) seeded stream windows, ``w_tiles`` (W, rows, cols),
     ``strip_ids``/``w_ids``/``valid_r`` (P,) int32. Each cell walks the
-    reduction rows with a fori_loop carrying the (t_seg + 1, cols)
-    partial-sum lo/hi planes — the (T, R, C) tensor never exists, VMEM holds
-    one strip window + one weight tile + two plane carries. K-padding rows
-    (r >= valid_r) would duplicate the previous row's count and are gated
-    out of the scalar sum; zero-padded w columns toggle nothing by
-    construction; valid_r == 0 turns dummy chunk-padding tasks off.
+    reduction rows carrying the (t_seg + 1, cols) partial-sum lo/hi planes —
+    the (T, R, C) tensor never exists, VMEM holds one strip window + one
+    weight tile + the plane carries. K-padding rows (r >= valid_r) would
+    duplicate the previous row's count and are gated out; zero-padded w
+    columns toggle nothing by construction; valid_r == 0 turns dummy
+    padding tasks off. Tasks run in pallas_calls of at most
+    ``MAX_CALL_TASKS`` (SMEM holds each call's metadata), ``LANE`` tasks
+    per lane-dense output row.
     Returns (P,) int32 totals; the caller reduces in int64 (each total <=
     t_seg*rows*cols*64 < 2^27 by the choose_block_t budget). Horizontal
     counts are per-strip, not per-task, and run in the sibling XLA strips
@@ -362,52 +419,50 @@ def activity_profile_pallas_tasks(
     """
     num_tasks = strip_ids.shape[0]
     t_seg1 = strips.shape[1]
+    per_call = min(MAX_CALL_TASKS, -(-num_tasks // LANE) * LANE)
+    n_calls = -(-num_tasks // per_call)
+    meta = jnp.stack([strip_ids, w_ids, valid_r]).astype(jnp.int32)
+    # zero padding routes to task 0's operands with valid_r == 0: counts 0
+    meta = jnp.pad(meta, ((0, 0), (0, n_calls * per_call - num_tasks)))
+    meta = meta.reshape(3, n_calls, per_call)
 
     def kernel(sid_ref, wid_ref, vr_ref, a_ref, w_ref, v_ref):
-        p = pl.program_id(0)
-        aw = a_ref[0]  # (t_seg + 1, rows)
-        w = w_ref[0]  # (rows, cols)
-        vr = vr_ref[p]
-
-        def body(r, carry):
-            run_lo, run_hi, acc = carry  # planes: (t_seg + 1, cols)
-            a_col = jax.lax.dynamic_index_in_dim(aw, r, axis=1, keepdims=False)
-            w_row = jax.lax.dynamic_index_in_dim(w, r, axis=0, keepdims=False)
-            prod = a_col[:, None] * w_row[None, :]
-            new_lo = run_lo + prod
-            if b_v <= 32:
-                # lo plane alone is exact for buses <= 32 bits (mod-2^32
-                # addition); skip the carry chain and the hi popcount
-                new_hi = run_hi
-                cnt = jnp.sum(value32_toggles(new_lo[1:], new_lo[:-1], b_v))
-            else:
-                c = (new_lo.astype(jnp.uint32) < run_lo.astype(jnp.uint32)).astype(
-                    jnp.int32
-                )
-                new_hi = run_hi + (prod >> jnp.int32(31)) + c
-                cnt = jnp.sum(
-                    planes_toggles(
-                        new_lo[1:], new_hi[1:], new_lo[:-1], new_hi[:-1], b_v
-                    )
-                )
-            return new_lo, new_hi, acc + jnp.where(r < vr, cnt, 0)
-
-        zero = jnp.zeros((t_seg1, cols), jnp.int32)
-        _, _, acc = jax.lax.fori_loop(0, rows, body, (zero, zero, jnp.int32(0)))
-        v_ref[0] = acc
+        g = pl.program_id(0)
+        j = pl.program_id(1)
+        vr = vr_ref[g * LANE + j]
+        run_lo = run_hi = jnp.zeros((t_seg1, cols), jnp.int32)
+        acc = jnp.zeros((t_seg1 - 1, cols), jnp.int32)
+        for r in range(rows):
+            prod = a_ref[0, :, r : r + 1] * w_ref[0, r : r + 1, :]
+            run_lo, run_hi = _add_row(run_lo, run_hi, prod, b_v)
+            acc += jnp.where(
+                r < vr,
+                planes_toggles(run_lo[1:], run_hi[1:], run_lo[:-1], run_hi[:-1], b_v),
+                0,
+            )
+        _store_lane(v_ref, j, jnp.sum(acc))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(num_tasks,),
+        grid=(per_call // LANE, LANE),
         in_specs=[
-            pl.BlockSpec((1, t_seg1, rows), lambda p, sid, wid, vr: (sid[p], 0, 0)),
-            pl.BlockSpec((1, rows, cols), lambda p, sid, wid, vr: (wid[p], 0, 0)),
+            pl.BlockSpec(
+                (1, t_seg1, rows),
+                lambda g, j, sid, wid, vr: (sid[g * LANE + j], 0, 0),
+            ),
+            pl.BlockSpec(
+                (1, rows, cols),
+                lambda g, j, sid, wid, vr: (wid[g * LANE + j], 0, 0),
+            ),
         ],
-        out_specs=pl.BlockSpec((1,), lambda p, sid, wid, vr: (p,)),
+        out_specs=pl.BlockSpec((1, 1, LANE), lambda g, j, sid, wid, vr: (g, 0, 0)),
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_tasks,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((per_call // LANE, 1, LANE), jnp.int32),
+        compiler_params=_CELL_SEMANTICS,
         interpret=interpret,
-    )(strip_ids, w_ids, valid_r, strips, w_tiles)
+    )
+    out = [call(*meta[:, c], strips, w_tiles).reshape(-1) for c in range(n_calls)]
+    return jnp.concatenate(out)[:num_tasks]

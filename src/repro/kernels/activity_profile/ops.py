@@ -422,7 +422,6 @@ def profile_gemm_toggles(
             rows=rows,
             cols=cols,
             k=k,
-            n=n,
             b_h=b_h,
             b_v=b_v,
             block_t=block_t,

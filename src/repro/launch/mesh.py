@@ -5,7 +5,7 @@ from __future__ import annotations
 import math
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,12 +26,11 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
             f"mesh {shape} needs {n} devices, have {len(devices)} "
             "(dry-runs must set --xla_force_host_platform_device_count)"
         )
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older signature without devices kwarg
-        from jax.sharding import Mesh
-
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    # Auto axes: the model code shards through with_sharding_constraint,
+    # which rejects specs on the Explicit axes jax.make_mesh defaults to.
+    return jax.make_mesh(
+        shape, axes, devices=devices[:n], axis_types=(AxisType.Auto,) * len(shape)
+    )
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int | None = None):

@@ -306,11 +306,6 @@ def moe_apply(p, x, cfg, dropless: bool = False) -> tuple[jnp.ndarray, jnp.ndarr
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # jax < 0.5 ships it under experimental
-        from jax.experimental.shard_map import shard_map
-
     from repro.parallel.sharding import active_act_rules, active_mesh
 
     b, s, d = x.shape
@@ -355,7 +350,7 @@ def moe_apply(p, x, cfg, dropless: bool = False) -> tuple[jnp.ndarray, jnp.ndarr
         else:
             cap_loc = max(int(t_loc * k_top * cfg.capacity_factor) // e, 1)
         cap_loc = -(-cap_loc // rep) * rep  # physical split must divide
-        disp = shard_map(
+        disp = jax.shard_map(
             lambda xl, il: _dispatch_local(xl, il, e, k_top, cap_loc, shards),
             mesh=mesh,
             in_specs=(P(tok_axes, None), P(tok_axes, None)),
@@ -370,7 +365,7 @@ def moe_apply(p, x, cfg, dropless: bool = False) -> tuple[jnp.ndarray, jnp.ndarr
         expert_out = jax.lax.with_sharding_constraint(
             expert_out, NamedSharding(mesh, P(None, tok_axes, None))
         )
-        comb = shard_map(
+        comb = jax.shard_map(
             lambda eo, de, gv: _combine_local(eo, de, gv, k_top),
             mesh=mesh,
             in_specs=(P(None, tok_axes, None), P(tok_axes), P(tok_axes, None)),

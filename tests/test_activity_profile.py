@@ -18,10 +18,12 @@ from repro.core.switching import (
     profile_cache_info,
     profile_gemm,
 )
+from repro.kernels.activity_profile.kernel import STREAM_LANE_BLOCK
 from repro.kernels.activity_profile.ops import (
     ToggleCounts,
     operands_fit_fused,
     profile_gemm_toggles,
+    stream_toggle_total,
 )
 from repro.kernels.activity_profile.ref import profile_gemm_toggles_ref
 
@@ -77,6 +79,38 @@ def test_pallas_kernel_small_block_t_carries_across_blocks():
         a, w, 16, 8, 16, 37, engine="pallas", interpret=True, block_t=8
     )
     assert (got.h_toggles, got.v_toggles, got.h_transitions, got.v_transitions) == ref
+
+
+def test_pallas_operand_stream_splits_wide_lane_bundles():
+    # more lanes than one grid cell takes: zero-padded lane blocks
+    x = RNG.integers(-32767, 32768, size=(40, 2 * STREAM_LANE_BLOCK + 52))
+    kw = dict(block_t=16)
+    got = stream_toggle_total(x, 24, engine="pallas", interpret=True, **kw)
+    assert got == stream_toggle_total(x, 24, engine="xla", **kw)
+
+
+@pytest.mark.parametrize("b_v", [23, 37])
+def test_pallas_tasks_split_across_calls(monkeypatch, b_v):
+    """More tasks than one call's SMEM metadata holds: several calls, each
+    of several lane-dense output groups, equal to the XLA rendering."""
+    from repro.kernels.activity_profile import kernel
+    from repro.kernels.activity_profile.batch import bucket_toggle_parts
+
+    monkeypatch.setattr(kernel, "MAX_CALL_TASKS", 256)
+    rows, cols, t_seg1, tasks = 8, 4, 9, 600
+    strips = RNG.integers(-32767, 32768, size=(10, t_seg1, rows)).astype(np.int32)
+    w_tiles = RNG.integers(-32767, 32768, size=(7, rows, cols)).astype(np.int32)
+    ids = RNG.integers(0, 10, tasks).astype(np.int32)
+    wids = RNG.integers(0, 7, tasks).astype(np.int32)
+    vr = RNG.integers(0, rows + 1, tasks).astype(np.int32)
+    got = kernel.activity_profile_pallas_tasks(
+        strips, w_tiles, ids, wids, vr, rows=rows, cols=cols, b_v=b_v, interpret=True
+    )
+    _, want, _ = bucket_toggle_parts(
+        strips, w_tiles, ids, wids, vr, rows=rows, cols=cols, b_h=16, b_v=b_v,
+        engine="xla",
+    )
+    assert np.array_equal(np.asarray(got), np.asarray(want)[:tasks])
 
 
 def test_fused_37bit_partial_sums_exact_at_extremes():

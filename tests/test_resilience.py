@@ -374,6 +374,10 @@ def test_timeout_evicts_device_and_resubmits(monkeypatch):
     # 16 k_tiles x 8 n_tiles = 128 tasks -> 2 shards on 2 devices
     a, w = _rand_gemm(16, 128, 64)
     job = ProfileJob(rows=8, cols=8, b_h=16, b_v=37, a=a, w=w, name="big")
+    # warm the compile cache first: the cold compile runs INSIDE the timed
+    # dispatch future, so on a loaded host it could trip the timeout on the
+    # healthy shard too
+    run_profile_batch([job], use_cache=False, engine="xla")
     health = HealthMonitor(range(2))
     with faults.injected(
         [faults.FaultSpec("hang", match="b0s1d1", max_fires=1)], hang_s=2.0
