@@ -48,6 +48,7 @@ from repro.layout.power import (
     ObjectiveSpec,
     evaluate_layout_space,
 )
+from repro.tracing import traced
 
 __all__ = ["evaluate_fleet_objective", "fleet_static_power"]
 
@@ -87,6 +88,7 @@ def fleet_static_power(
     return np.asarray(fixed + compute, float)
 
 
+@traced("price")
 def evaluate_fleet_objective(
     grid,
     a_h,

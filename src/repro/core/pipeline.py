@@ -70,6 +70,7 @@ quarantined-and-recomputed corrupt store entries there too.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -104,6 +105,7 @@ from repro.runtime.resilience import (
     classify_exception,
     degradation_ladder,
 )
+from repro.tracing import span, traced
 
 __all__ = [
     "ProfileJob",
@@ -165,8 +167,9 @@ class ProfileJob:
                     f"job {self.name!r} has neither operands nor make",
                     job=self.name,
                 )
-            a, w = self.make()
-            self.a, self.w = np.asarray(a), np.asarray(w)
+            with span("profile.synthesize"):
+                a, w = self.make()
+                self.a, self.w = np.asarray(a), np.asarray(w)
         a = np.asarray(self.a, dtype=np.int64)
         w = np.asarray(self.w, dtype=np.int64)
         if a.ndim != 2 or w.ndim != 2 or a.shape[1] != w.shape[0]:
@@ -340,6 +343,7 @@ def _fused_eligible(job: ProfileJob, a: np.ndarray, w: np.ndarray) -> bool:
     return operands_fit_fused(a, w)
 
 
+@traced("profile.schedule")
 def _schedule_job(job, a, w, t_trim, bucket_map, buckets, pass_map, stats):
     """Attach one job to a (possibly shared) device pass, creating buckets
     and stacking segment strips / weight tiles / tasks as needed. Returns
@@ -401,6 +405,7 @@ def _schedule_job(job, a, w, t_trim, bucket_map, buckets, pass_map, stats):
     return pass_key
 
 
+@traced("profile.schedule")
 def _schedule_os_job(
     job, a, w, stream_bucket_map, stream_buckets, stream_pass_map, stats
 ):
@@ -658,27 +663,29 @@ def run_profile_batch(
     # then materialize + dispatch bucket by bucket: while bucket i compiles
     # (worker thread) and computes on-device, the main thread synthesizes
     # bucket i+1's operands.
-    order: dict[tuple, list[int]] = {}
-    for i, job in enumerate(jobs):
-        order.setdefault(_bucket_key(job), []).append(i)
+    with span("profile.setup"):
+        order: dict[tuple, list[int]] = {}
+        for i, job in enumerate(jobs):
+            order.setdefault(_bucket_key(job), []).append(i)
 
-    # Device fan-out: each bucket's TASK axis is sharded across the local
-    # devices (contiguous slices, padded to one shared shape class so every
-    # shard reuses the same compiled program) and the shards execute
-    # genuinely in parallel — on TPU pods, or on CPU hosts running with
-    # ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. The serial
-    # per-GEMM path cannot do this: it blocks on every layer's result.
-    # A backend that fails to initialise raises here: the pipeline never
-    # runs on a placeholder device.
-    import jax
+        # Device fan-out: each bucket's TASK axis is sharded across the local
+        # devices (contiguous slices, padded to one shared shape class so every
+        # shard reuses the same compiled program) and the shards execute
+        # genuinely in parallel — on TPU pods, or on CPU hosts running with
+        # ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. The serial
+        # per-GEMM path cannot do this: it blocks on every layer's result.
+        # A backend that fails to initialise raises here: the pipeline never
+        # runs on a placeholder device.
+        import jax
 
-    devices = list(devices) if devices is not None else jax.local_devices()
+        devices = list(devices) if devices is not None else jax.local_devices()
 
-    if health is None:
-        health = HealthMonitor(range(len(devices)))
+        if health is None:
+            health = HealthMonitor(range(len(devices)))
 
-    executor = ThreadPoolExecutor(max_workers=max(2, len(devices)))
+        executor = ThreadPoolExecutor(max_workers=max(2, len(devices)))
 
+    @traced("profile.dispatch")
     def _run_shard(args, kw, device_index, site):
         """Executor task for one shard: fault hooks, compile + dispatch,
         BLOCKING reduce — so ``future.result(timeout=...)`` bounds the whole
@@ -694,11 +701,12 @@ def run_profile_batch(
     def _submit_bucket(bidx: int, b: _Bucket) -> list[_Shard]:
         """One executor task per shard: shard compiles (each device binding
         compiles its own executable) and executions all run concurrently."""
-        strips = np.stack(b.strips)
-        w_tiles = np.stack(b.w_tiles)
-        ids = np.asarray(b.strip_ids, np.int32)
-        wids = np.asarray(b.w_ids, np.int32)
-        vr = np.asarray(b.valid_r, np.int32)
+        with span("profile.stack"):
+            strips = np.stack(b.strips)
+            w_tiles = np.stack(b.w_tiles)
+            ids = np.asarray(b.strip_ids, np.int32)
+            wids = np.asarray(b.w_ids, np.int32)
+            vr = np.asarray(b.valid_r, np.int32)
         n_shards = min(len(devices), max(1, len(ids) // 64))
         kw = dict(
             rows=b.rows, cols=b.cols, b_h=b.b_h, b_v=b.b_v,
@@ -738,6 +746,7 @@ def run_profile_batch(
             )
         return shards
 
+    @traced("profile.dispatch")
     def _run_stream(strips, bits, site):
         inj = faults.active()
         if inj is not None:
@@ -793,13 +802,6 @@ def run_profile_batch(
 
     prefetch_pool = ThreadPoolExecutor(max_workers=1)
     try:
-        # Pay the one-time XLA/LLVM backend spin-up concurrently with the
-        # first bucket's operand synthesis instead of inside its (timed)
-        # first compile.
-        import jax.numpy as jnp
-
-        executor.submit(jax.jit(lambda x: x + 1), jnp.zeros(8, jnp.int32))
-
         # Materialize lazy operands a bounded window ahead on a side thread
         # (numpy synthesis releases the GIL), in the same order the group
         # loop consumes them — the window keeps host memory at a few jobs'
@@ -813,7 +815,14 @@ def run_profile_batch(
                 nxt = consume_order.pop(0)
                 prefetched[nxt] = prefetch_pool.submit(jobs[nxt].operands)
 
-        _advance_prefetch()
+        with span("profile.setup"):
+            # Pay the one-time XLA/LLVM backend spin-up concurrently with the
+            # first bucket's operand synthesis instead of inside its (timed)
+            # first compile.
+            import jax.numpy as jnp
+
+            executor.submit(jax.jit(lambda x: x + 1), jnp.zeros(8, jnp.int32))
+            _advance_prefetch()
 
         for bkey, members in order.items():
             t_trim = max(
@@ -822,7 +831,8 @@ def run_profile_batch(
             for i in members:
                 job = jobs[i]
                 try:
-                    a, w = prefetched.pop(i).result()
+                    with span("profile.synth_wait"):
+                        a, w = prefetched.pop(i).result()
                 except Exception as exc:
                     # Malformed jobs are programming errors: typed, and
                     # raised in EVERY mode (skipping them would hide bugs).
@@ -830,18 +840,22 @@ def run_profile_batch(
                         exc, job=job.label(i), stage="schedule"
                     ) from exc
                 _advance_prefetch()
-                resolved = _resolve_backend(backend, a, w, job.rows, job.dataflow)
+                with span("profile.check"):
+                    resolved = _resolve_backend(backend, a, w, job.rows, job.dataflow)
                 if use_cache:
-                    key = _cache_key(
-                        a, w, job.rows, job.cols, job.b_h, job.b_v,
-                        (resolved, job.dataflow, "exact"),
-                    )
+                    with span("profile.key"):
+                        key = _cache_key(
+                            a, w, job.rows, job.cols, job.b_h, job.b_v,
+                            (resolved, job.dataflow, "exact"),
+                        )
                     hit, _source = _cache_get(key)
                     if hit is not None:
                         resolution[i] = ("cache", hit)
                         stats.cache_hits += 1
                         continue
-                if resolved == "numpy" or not _fused_eligible(job, a, w):
+                with span("profile.check"):
+                    fused = resolved != "numpy" and _fused_eligible(job, a, w)
+                if not fused:
                     if requested == "pallas" and resolved != "numpy":
                         # match profile_gemm(backend="pallas"): loud
                         # contract failure instead of a silent oracle detour
@@ -872,14 +886,13 @@ def run_profile_batch(
                 # the (int32) strip copies, so keeping every job's int64
                 # operands alive until collection would scale host memory
                 # with the whole workload.
-                store_key = (
-                    _cache_key(
-                        a, w, job.rows, job.cols, job.b_h, job.b_v,
-                        ("pallas", job.dataflow, "exact"),
-                    )
-                    if use_cache
-                    else None
-                )
+                store_key = None
+                if use_cache:
+                    with span("profile.key"):
+                        store_key = _cache_key(
+                            a, w, job.rows, job.cols, job.b_h, job.b_v,
+                            ("pallas", job.dataflow, "exact"),
+                        )
                 resolution[i] = (
                     kind,
                     (keys, float(np.mean(a == 0)), int(a.size), store_key),
@@ -901,9 +914,9 @@ def run_profile_batch(
         # fraction of the device work — so the lost overlap is nil.
         for sidx, b in enumerate(stream_buckets):
             if b.future is None and b.strips:
-                b.future = executor.submit(
-                    _run_stream, np.stack(b.strips), b.bits, f"sb{sidx}"
-                )
+                with span("profile.stack"):
+                    strips = np.stack(b.strips)
+                b.future = executor.submit(_run_stream, strips, b.bits, f"sb{sidx}")
 
         stats.buckets = len(buckets) + len(stream_buckets)
         stats.tasks = sum(len(b.strip_ids) for b in buckets)
@@ -916,41 +929,46 @@ def run_profile_batch(
         # shard 0 (identical in all shards), v concatenates the contiguous
         # task slices back together.  A bucket whose shards cannot be
         # recovered records its typed error; its jobs are degraded or
-        # skipped per job below.
-        reduced = []
-        for b in buckets:
-            if not b.shards:
-                reduced.append(None)
-                continue
-            h_tot = None
-            v_chunks = []
-            for si, shard in enumerate(b.shards):
-                h, v, err = _await_shard(shard)
-                if err is not None:
+        # skipped per job below.  The span opens only when something was
+        # dispatched: a batch served wholly from the cache waits on nothing.
+        dispatched = any(b.shards for b in buckets) or any(
+            b.future is not None for b in stream_buckets
+        )
+        with span("profile.collect") if dispatched else contextlib.nullcontext():
+            reduced = []
+            for b in buckets:
+                if not b.shards:
+                    reduced.append(None)
+                    continue
+                h_tot = None
+                v_chunks = []
+                for si, shard in enumerate(b.shards):
+                    h, v, err = _await_shard(shard)
+                    if err is not None:
+                        b.error = err
+                        break
+                    if si == 0:
+                        h_tot = h
+                    v_chunks.append(v)
+                if b.error is not None:
+                    reduced.append(None)
+                    continue
+                reduced.append(
+                    (h_tot, np.concatenate(v_chunks)[: len(b.strip_ids)])
+                )
+            stream_reduced = []
+            for b in stream_buckets:
+                if b.future is None:
+                    stream_reduced.append(None)
+                    continue
+                try:
+                    stream_reduced.append(b.future.result(timeout=timeout_s))
+                except Exception as exc:
+                    err = classify_exception(exc, stage="dispatch")
+                    if mode == "raise":
+                        raise err from exc
                     b.error = err
-                    break
-                if si == 0:
-                    h_tot = h
-                v_chunks.append(v)
-            if b.error is not None:
-                reduced.append(None)
-                continue
-            reduced.append(
-                (h_tot, np.concatenate(v_chunks)[: len(b.strip_ids)])
-            )
-        stream_reduced = []
-        for b in stream_buckets:
-            if b.future is None:
-                stream_reduced.append(None)
-                continue
-            try:
-                stream_reduced.append(b.future.result(timeout=timeout_s))
-            except Exception as exc:
-                err = classify_exception(exc, stage="dispatch")
-                if mode == "raise":
-                    raise err from exc
-                b.error = err
-                stream_reduced.append(None)
+                    stream_reduced.append(None)
     finally:
         executor.shutdown(wait=True)
         prefetch_pool.shutdown(wait=True)
@@ -981,59 +999,60 @@ def run_profile_batch(
         report.add(cause, action="skipped", job=label, stage="collect")
         return None
 
-    profiles: list[ActivityProfile | None] = []
-    for i, job in enumerate(jobs):
-        kind, payload = resolution[i]
-        if kind == "cache":
-            profiles.append(payload)
-            continue
-        if kind == "serial":
-            profiles.append(_serial_job(job, i, payload))
-            continue
-        key, zero_fraction, elements, store_key = payload
-        m, k, n = job.gemm_shape()
-        n_tiles = -(-n // job.cols)
-        if kind == "os_pass":
-            key_a, key_w = key
-            sps = (stream_pass_map[key_a], stream_pass_map[key_w])
-            if any(sp.total is None for sp in sps):
-                cause = next(
-                    stream_buckets[sp.bucket].error
-                    for sp in sps
-                    if sp.total is None
-                )
-                profiles.append(_recover_or_skip(i, job, cause, store_key))
+    with span("profile.assemble"):
+        profiles: list[ActivityProfile | None] = []
+        for i, job in enumerate(jobs):
+            kind, payload = resolution[i]
+            if kind == "cache":
+                profiles.append(payload)
                 continue
-            # Geometry-free stream totals fold through the shared OS
-            # accounting identity with each job's own output tiling.
-            counts = ToggleCounts(
-                *os_stream_counts(
-                    sps[0].total, sps[1].total, m, k, n, job.rows, job.cols
+            if kind == "serial":
+                profiles.append(_serial_job(job, i, payload))
+                continue
+            key, zero_fraction, elements, store_key = payload
+            m, k, n = job.gemm_shape()
+            n_tiles = -(-n // job.cols)
+            if kind == "os_pass":
+                key_a, key_w = key
+                sps = (stream_pass_map[key_a], stream_pass_map[key_w])
+                if any(sp.total is None for sp in sps):
+                    cause = next(
+                        stream_buckets[sp.bucket].error
+                        for sp in sps
+                        if sp.total is None
+                    )
+                    profiles.append(_recover_or_skip(i, job, cause, store_key))
+                    continue
+                # Geometry-free stream totals fold through the shared OS
+                # accounting identity with each job's own output tiling.
+                counts = ToggleCounts(
+                    *os_stream_counts(
+                        sps[0].total, sps[1].total, m, k, n, job.rows, job.cols
+                    )
                 )
+                a_h, a_v = counts.activities(job.b_h, job.b_v)
+                profiles.append(
+                    _store_profile(
+                        job, counts, a_h, a_v, zero_fraction, elements, store_key
+                    )
+                )
+                continue
+            p = pass_map[key]
+            if p.h_total is None:
+                profiles.append(
+                    _recover_or_skip(i, job, buckets[p.bucket].error, store_key)
+                )
+                continue
+            counts = ToggleCounts(
+                n_tiles * p.h_total,
+                p.v_total,
+                max(m - 1, 0) * k * n_tiles,
+                max(m - 1, 0) * k * n,
             )
             a_h, a_v = counts.activities(job.b_h, job.b_v)
             profiles.append(
-                _store_profile(
-                    job, counts, a_h, a_v, zero_fraction, elements, store_key
-                )
+                _store_profile(job, counts, a_h, a_v, zero_fraction, elements, store_key)
             )
-            continue
-        p = pass_map[key]
-        if p.h_total is None:
-            profiles.append(
-                _recover_or_skip(i, job, buckets[p.bucket].error, store_key)
-            )
-            continue
-        counts = ToggleCounts(
-            n_tiles * p.h_total,
-            p.v_total,
-            max(m - 1, 0) * k * n_tiles,
-            max(m - 1, 0) * k * n,
-        )
-        a_h, a_v = counts.activities(job.b_h, job.b_v)
-        profiles.append(
-            _store_profile(job, counts, a_h, a_v, zero_fraction, elements, store_key)
-        )
     return _finish(profiles)
 
 

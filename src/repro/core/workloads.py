@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.core.quant import quantize_symmetric
 from repro.core.switching import ActivityProfile, profile_gemm
+from repro.tracing import traced
 
 __all__ = [
     "ConvLayer",
@@ -346,6 +347,7 @@ def _activity_classes(grid) -> tuple[list[tuple], np.ndarray]:
     return classes, uniq_class[inverse]
 
 
+@traced("profile")
 def measured_design_activities(
     grid,
     layers: Sequence[ConvLayer] = RESNET50_TABLE1,
@@ -443,6 +445,7 @@ def gemm_profile_seed(
     return int.from_bytes(hashlib.sha256(key).digest()[:4], "little")
 
 
+@traced("profile.jobs")
 def design_gemm_jobs(
     grid,
     gemms: Sequence[Gemm],
@@ -516,6 +519,7 @@ def design_gemm_jobs(
     return jobs, gemm_uniq, point_class
 
 
+@traced("profile")
 def measured_design_gemm_activities(
     grid,
     gemms: Sequence[Gemm],

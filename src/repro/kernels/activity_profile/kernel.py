@@ -273,6 +273,7 @@ def activity_profile_pallas(
     out_shape = jax.ShapeDtypeStruct((num_tiles, 1, num_tb), jnp.int32)
     return pl.pallas_call(
         kernel,
+        name="activity_profile_pallas",
         grid=(num_tiles, num_tb),
         in_specs=[
             pl.BlockSpec((1, block_t, rows), lambda p, j: (p // n_tiles, j, 0)),
@@ -329,6 +330,7 @@ def operand_stream_toggles_pallas(
 
     return pl.pallas_call(
         kernel,
+        name="operand_stream_toggles_pallas",
         grid=(num_lb, num_tb),
         in_specs=[pl.BlockSpec((block_t, block_l), lambda i, j: (j, i))],
         out_specs=pl.BlockSpec((1, 1, num_tb), lambda i, j: (i, 0, 0)),
@@ -364,6 +366,7 @@ def stream_strips_toggles_pallas(
 
     out = pl.pallas_call(
         kernel,
+        name="stream_strips_toggles_pallas",
         grid=(groups, LANE),
         in_specs=[
             # cells past the last strip re-read it; their lanes are dropped
@@ -459,6 +462,7 @@ def activity_profile_pallas_tasks(
     )
     call = pl.pallas_call(
         kernel,
+        name="activity_profile_pallas_tasks",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((per_call // LANE, 1, LANE), jnp.int32),
         compiler_params=_CELL_SEMANTICS,

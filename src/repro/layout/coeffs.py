@@ -43,6 +43,7 @@ import numpy as np
 from repro.core.optimize import bus_invert_activity_arr
 from repro.layout.geometry import envelope_coeffs, get_layout
 from repro.layout.segments import DATA_NETS, SEGMENT_CLASS_SCHEMA, segment_class_coeffs
+from repro.tracing import traced
 
 __all__ = [
     "LoweredCoeffs",
@@ -196,6 +197,7 @@ def _content_key(grid, layout_names, max_envelope_aspect, spacing) -> str:
     return h.hexdigest()
 
 
+@traced("lower.lower_layout_coeffs")
 def lower_layout_coeffs(
     grid,
     layouts,
@@ -367,6 +369,7 @@ def _partition_key(grid, layout_names, gemms) -> str:
     return h.hexdigest()
 
 
+@traced("lower.lower_partition_coeffs")
 def lower_partition_coeffs(grid, layouts, gemms) -> LoweredTensors:
     """Lower the pod-partition model into (gemm, layout, point) arrays.
 
@@ -469,6 +472,7 @@ CODING_SCHEMES = {
 }
 
 
+@traced("lower.grid_coding_effective")
 def grid_coding_effective(grid, a_v, xp=np):
     """Effective (coded) vertical activity per (workload, point), host f64.
 
@@ -510,6 +514,7 @@ def _coding_key(grid, a_v) -> str:
     return h.hexdigest()
 
 
+@traced("lower.lower_coding_multipliers")
 def lower_coding_multipliers(grid, a_v) -> LoweredTensors:
     """Lower the grid's coding axis to (workload, data-class, point) factors.
 

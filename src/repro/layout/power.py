@@ -516,6 +516,9 @@ def _coeff_eval_core(
 @functools.lru_cache(maxsize=32)
 def _jitted_coeff_eval(rep_idx: tuple, nb: int, nn: int, donate: bool):
     fn = functools.partial(_coeff_eval_core, rep_idx=rep_idx, nb=nb, nn=nn)
+    # named after the core, so the program is jit__coeff_eval_core in HLO and
+    # in profiles (JAX calls a bare partial jit__unknown)
+    functools.update_wrapper(fn, _coeff_eval_core)
     if donate:
         # Chunked sweeps slice fresh per-chunk coefficient buffers; donating
         # them lets XLA reuse the allocations instead of doubling footprint.

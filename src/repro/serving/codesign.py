@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs.registry import ArchConfig, get_arch
 from repro.core.design_space import DesignSpace
 from repro.core.objective import evaluate_fleet_objective
+from repro.core.pipeline import BatchStats
 from repro.core.workloads import (
     RESNET50_TABLE1,
     conv_to_gemm,
@@ -38,6 +39,7 @@ from repro.core.workloads import (
     measured_design_gemm_activities,
 )
 from repro.serving.traffic import ServingJobSet, TrafficModel, get_preset, weighted_gemms
+from repro.tracing import span
 
 __all__ = [
     "CodesignResult",
@@ -71,6 +73,7 @@ class CodesignResult:
     grid: object  # DesignGrid
     eval: object  # LayoutSpaceEval with J/op + macs_per_token priced
     layouts: tuple[str, ...]
+    profile_stats: BatchStats  # the scheduler's counters of the answer's profiling
 
     @property
     def best_cell(self) -> tuple[int, int]:
@@ -120,38 +123,45 @@ def codesign(
     use_jit: bool | None = None,
     sweep=None,
 ) -> CodesignResult:
-    """Measured end-to-end serving co-design for one (model, traffic) pair."""
+    """Measured end-to-end serving co-design for one (model, traffic) pair.
+
+    Runs inside the root span ``repro.codesign`` (``repro.tracing``); the
+    result carries the ``BatchStats`` of its one profiling batch.
+    """
     cfg = get_arch(arch) if isinstance(arch, str) else arch
     tm = get_preset(traffic) if isinstance(traffic, str) else traffic
-    jobset = weighted_gemms(cfg, tm)
-    grid = space.expand()
-    a_h, a_v = measured_design_gemm_activities(
-        grid,
-        jobset.gemms,
-        densities=jobset.densities,
-        clip=clip,
-        backend=backend,
-        use_cache=use_cache,
-    )
-    ev = evaluate_fleet_objective(
-        grid,
-        a_h,
-        a_v,
-        jobset.gemms,
-        layouts=tuple(layouts),
-        weights=jobset.weights,
-        use_jit=use_jit,
-        sweep=sweep,
-        macs_per_token=jobset.macs_per_token,
-    )
-    return CodesignResult(
-        arch=jobset.arch,
-        traffic=jobset.traffic,
-        jobset=jobset,
-        grid=grid,
-        eval=ev,
-        layouts=tuple(layouts),
-    )
+    with span("codesign", arch=cfg.name, traffic=tm.name):
+        jobset = weighted_gemms(cfg, tm)
+        grid = space.expand()
+        a_h, a_v, stats = measured_design_gemm_activities(
+            grid,
+            jobset.gemms,
+            densities=jobset.densities,
+            clip=clip,
+            backend=backend,
+            use_cache=use_cache,
+            return_stats=True,
+        )
+        ev = evaluate_fleet_objective(
+            grid,
+            a_h,
+            a_v,
+            jobset.gemms,
+            layouts=tuple(layouts),
+            weights=jobset.weights,
+            use_jit=use_jit,
+            sweep=sweep,
+            macs_per_token=jobset.macs_per_token,
+        )
+        return CodesignResult(
+            arch=jobset.arch,
+            traffic=jobset.traffic,
+            jobset=jobset,
+            grid=grid,
+            eval=ev,
+            layouts=tuple(layouts),
+            profile_stats=stats,
+        )
 
 
 def cnn_reference(

@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.core.workloads import Gemm
 from repro.serving.expand import ServingGemm, expand_arch
+from repro.tracing import traced
 
 __all__ = [
     "TrafficModel",
@@ -240,6 +241,7 @@ class ServingJobSet:
         return np.asarray(self.weights) * mask
 
 
+@traced("expand")
 def weighted_gemms(cfg, tm: TrafficModel, *, arch_name: str | None = None) -> ServingJobSet:
     """Expand ``cfg`` under every traffic class and weight by MAC share.
 

@@ -5,8 +5,12 @@ and at one full-width Mixtral expert-FFN shape (K = 4096, N = 14336).
 
 Interpret mode cannot see the TPU tiling and memory rules these compiles
 enforce. Nothing runs, so results are covered by the interpret-mode tests
-and by ``chip_smoke.py`` on the chip.
+and by ``chip_smoke.py`` on the chip. The names that profiles know the
+device programs by are checked in the compiled text too: the kernels' op
+names, and the fused J/op program's name (a CPU lowering).
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -88,3 +92,51 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip) for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# Profiles find the profiling kernels by the HLO op name their jitted
+# functions give the custom call (``%activity_profile_pallas_tasks.N =
+# ... custom-call``), whatever name the Pallas kernel itself carries.
+KERNEL_OPS = {
+    "tasks_bv_le32_codesign": "activity_profile_pallas_tasks",
+    "strips_codesign": "stream_strips_toggles_pallas",
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_OPS))
+def test_kernel_op_keeps_its_name(one_chip, case):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip) for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    op = re.compile(rf"^%{KERNEL_OPS[case]}[.\d]* = .*custom-call")
+    assert any(op.match(line.strip()) for line in text.splitlines())
+
+
+def test_jop_program_is_named_after_its_core(monkeypatch):
+    """The fused J/op program lowers as ``jit__coeff_eval_core`` (a bare
+    ``functools.partial`` would lower as ``jit__unknown``)."""
+    from repro.core.design_space import DesignSpace
+    from repro.core.objective import evaluate_fleet_objective
+    from repro.core.workloads import Gemm
+    from repro.layout import power
+
+    calls = []
+    real = power._jitted_coeff_eval
+
+    def spy(*key):
+        fn = real(*key)
+
+        def call(*args):
+            calls.append((fn, args))
+            return fn(*args)
+
+        return call
+
+    monkeypatch.setattr(power, "_jitted_coeff_eval", spy)
+    grid = DesignSpace(
+        rows=(8,), cols=(8, 16), input_bits=(8,), dataflows=("WS",), bus_invert=(False,)
+    ).expand()
+    evaluate_fleet_objective(grid, 0.2, 0.3, [Gemm("g", 16, 32, 16)], layouts=("uniform",),
+                             use_jit=True)
+    ((fn, args),) = calls
+    assert fn.lower(*args).as_text().startswith("module @jit__coeff_eval_core")
