@@ -13,7 +13,10 @@ jobs into a handful of fused device programs:
      single device pass (``a``'s horizontal toggles are geometry-independent
      up to ceil(N/cols) scaling, and the vertical totals depend on ``rows``
      but not ``cols`` — tiling the columns differently regroups, never
-     changes, the per-column partial-sum streams).
+     changes, the per-column partial-sum streams).  A job that declares an
+     operand recipe is keyed from the recipe memo when its recipe is known
+     (a hit then synthesizes nothing), and each recipe is synthesized,
+     range-checked and digested once per batch.
   2. **Bucketing** — schedulable jobs are grouped into a small set of padded
      shape classes: same (rows, cols, b_h, b_v) and time extents rounded up
      to a shared power-of-two block count (≤2x T padding, count-neutral).
@@ -75,18 +78,20 @@ import dataclasses
 import os
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Sequence
+from typing import Callable, Hashable, Sequence
 
 import numpy as np
 
 from repro.core.switching import (
     ActivityProfile,
+    OperandFacts,
     _cache_get,
-    _cache_key,
     _cache_put,
     _note_batch_stores,
-    _operand_digest,
-    _resolve_backend,
+    _operand_facts,
+    _recipe_facts,
+    _remember_recipe,
+    _resolve_backend_fit,
     DEFAULT_BACKEND,
     os_stream_counts,
     profile_gemm,
@@ -133,6 +138,12 @@ class ProfileJob:
     pipeline overlap operand synthesis with device work, and let bucket
     planning see shapes without materializing anything.  ``dataflow``
     selects the stream model ("WS" partial sums / "OS" operand streams).
+
+    ``recipe`` (lazy jobs, optional) is a hashable of exactly what ``make``
+    reads: jobs with equal recipes must synthesize byte-equal operands.
+    The pipeline then synthesizes, range-checks and digests each recipe
+    once, and keys a job whose recipe it already knows without
+    synthesizing (``repro.core.switching``'s recipe memo).
     """
 
     rows: int
@@ -145,6 +156,7 @@ class ProfileJob:
     shape: tuple[int, int, int] | None = None
     name: str = ""
     dataflow: str = "WS"
+    recipe: Hashable | None = None
 
     def label(self, index: int) -> str:
         return self.name or f"job{index}"
@@ -204,6 +216,8 @@ class BatchStats:
     degraded: int = 0  # jobs recovered per-job after a batched-path failure
     skipped: int = 0  # jobs returned as None under on_error="skip"
     resubmits: int = 0  # device shards resubmitted after eviction
+    synthesized: int = 0  # lazy operand pairs built (once per recipe)
+    recipe_hits: int = 0  # jobs keyed from the recipe memo, no operands built
     failure_report: FailureReport = dataclasses.field(default_factory=FailureReport)
 
     def as_dict(self) -> dict:
@@ -320,13 +334,13 @@ def _bucket_key(job: ProfileJob) -> tuple:
     return (job.rows, job.cols, job.b_h, job.b_v, t_seg)
 
 
-def _fused_eligible(job: ProfileJob, a: np.ndarray, w: np.ndarray) -> bool:
-    """Mirror of profile_gemm_toggles' contract checks (raise-free)."""
+def _fused_eligible(job: ProfileJob, fits: bool) -> bool:
+    """Mirror of profile_gemm_toggles' contract checks (raise-free);
+    ``fits``: the operands fit int16 (``OperandFacts.fits_fused``)."""
     from repro.kernels.activity_profile.ops import (
         MAX_FUSED_K,
         MAX_FUSED_LANES,
         MAX_FUSED_ROWS,
-        operands_fit_fused,
     )
 
     m, k, n = job.gemm_shape()
@@ -335,16 +349,16 @@ def _fused_eligible(job: ProfileJob, a: np.ndarray, w: np.ndarray) -> bool:
             return False  # zero transitions: serial path returns zeros instantly
         if max(m, n) >= MAX_FUSED_LANES:
             return False
-        return operands_fit_fused(a, w)
+        return fits
     if m < 2 or k == 0 or n == 0:
         return False  # zero transitions: serial path returns zeros instantly
     if k + job.rows >= MAX_FUSED_K or job.rows >= MAX_FUSED_ROWS:
         return False
-    return operands_fit_fused(a, w)
+    return fits
 
 
 @traced("profile.schedule")
-def _schedule_job(job, a, w, t_trim, bucket_map, buckets, pass_map, stats):
+def _schedule_job(job, a, w, facts, t_trim, bucket_map, buckets, pass_map, stats):
     """Attach one job to a (possibly shared) device pass, creating buckets
     and stacking segment strips / weight tiles / tasks as needed. Returns
     the job's pass key. ``t_trim`` caps the bucket's segment length at the
@@ -356,8 +370,7 @@ def _schedule_job(job, a, w, t_trim, bucket_map, buckets, pass_map, stats):
     # Shapes are part of the key: digests hash raw bytes, and the same bytes
     # reshaped to a different (M, K)/(K, N) are a different stream.
     pass_key = (
-        _operand_digest(a), _operand_digest(w), (m, k, n),
-        job.rows, job.b_h, job.b_v,
+        facts.a_digest, facts.w_digest, (m, k, n), job.rows, job.b_h, job.b_v,
     )
     if pass_key in pass_map:
         stats.pass_reuse += 1
@@ -407,7 +420,7 @@ def _schedule_job(job, a, w, t_trim, bucket_map, buckets, pass_map, stats):
 
 @traced("profile.schedule")
 def _schedule_os_job(
-    job, a, w, stream_bucket_map, stream_buckets, stream_pass_map, stats
+    job, a, w, facts, stream_bucket_map, stream_buckets, stream_pass_map, stats
 ):
     """Attach one OS job to its two operand-stream passes (A rows at b_h,
     W columns at b_v), creating stream buckets as needed.  Pass keys carry
@@ -418,11 +431,11 @@ def _schedule_os_job(
 
     m, k, n = job.gemm_shape()
     keys = []
-    for tag, arr, shape, bits in (
-        ("A", a, (m, k), job.b_h),
-        ("W", w, (k, n), job.b_v),
+    for tag, arr, digest, shape, bits in (
+        ("A", a, facts.a_digest, (m, k), job.b_h),
+        ("W", w, facts.w_digest, (k, n), job.b_v),
     ):
-        key = ("os", tag, _operand_digest(arr), shape, bits)
+        key = ("os", tag, digest, shape, bits)
         keys.append(key)
         if key in stream_pass_map:
             stats.pass_reuse += 1
@@ -652,12 +665,46 @@ def run_profile_batch(
     # resolution[i]: ("cache", profile) | ("pass", key) | ("os_pass", keys)
     #             | ("serial", backend) | ("failed", typed error)
     resolution: list[tuple] = [None] * len(jobs)
+    # facts[i]: job i's OperandFacts; looked_up[i]: its (backend, store key)
+    # once the content cache has been asked for it
+    facts: list[OperandFacts | None] = [None] * len(jobs)
+    looked_up: list[tuple | None] = [None] * len(jobs)
     bucket_map: dict[tuple, int] = {}
     buckets: list[_Bucket] = []
     pass_map: dict[tuple, _Pass] = {}
     stream_bucket_map: dict[tuple, int] = {}
     stream_buckets: list[_StreamBucket] = []
     stream_pass_map: dict[tuple, _StreamPass] = {}
+
+    def _look_up(i: int) -> tuple[str, bytes | None]:
+        """Backend and content-cache lookup of job i from its facts (a hit
+        resolves the job); returns ``(backend, store key)``."""
+        job, f = jobs[i], facts[i]
+        resolved = _resolve_backend_fit(
+            backend, f.a_shape, f.w_shape, job.rows, job.dataflow,
+            lambda: f.fits_fused, stacklevel=5,
+        )
+        store_key = None
+        if use_cache:
+            geometry = (job.rows, job.cols, job.b_h, job.b_v)
+            with span("profile.key"):
+                key = f.cache_key(*geometry, (resolved, job.dataflow, "exact"))
+                store_key = key if resolved == "pallas" else f.cache_key(
+                    *geometry, ("pallas", job.dataflow, "exact")
+                )
+            hit, _source = _cache_get(key)
+            if hit is not None:
+                resolution[i] = ("cache", hit)
+                stats.cache_hits += 1
+        return resolved, store_key
+
+    # A job whose recipe the memo knows is keyed and looked up before any
+    # synthesis: a hit never enters the prefetch queue.
+    for i, job in enumerate(jobs):
+        if job.recipe is not None and (f := _recipe_facts(job.recipe)) is not None:
+            facts[i] = f
+            stats.recipe_hits += 1
+            looked_up[i] = _look_up(i)
 
     # Group by shape class first (shapes are declared, operands still lazy),
     # then materialize + dispatch bucket by bucket: while bucket i compiles
@@ -804,16 +851,33 @@ def run_profile_batch(
     try:
         # Materialize lazy operands a bounded window ahead on a side thread
         # (numpy synthesis releases the GIL), in the same order the group
-        # loop consumes them — the window keeps host memory at a few jobs'
-        # operands, not the whole workload's.
-        consume_order = [i for members in order.values() for i in members]
-        prefetched: dict[int, object] = {}
+        # loop consumes them: each recipe once, at its first job, and each
+        # job without a recipe on its own.  A recipe's operands are held,
+        # refcounted, until the last of its jobs is scheduled, so host
+        # memory holds the batch's distinct recipes (~1.5 MB each at the
+        # default clip) plus the window, not one copy per job.
+        unit: dict[int, tuple] = {}  # job -> ("recipe", r) | ("job", i)
+        refs: dict[tuple, int] = {}  # unit -> jobs still to consume it
+        consume_order = []  # the first job of each unit
+        for i in (i for members in order.values() for i in members):
+            if resolution[i] is not None:
+                continue
+            r = jobs[i].recipe
+            u = unit[i] = ("job", i) if r is None else ("recipe", r)
+            if u not in refs:
+                refs[u] = 0
+                consume_order.append(i)
+            refs[u] += 1
+        prefetched: dict[tuple, object] = {}  # unit -> future of (a, w)
+        held: dict[tuple, tuple] = {}  # unit -> (a, w) while refs[unit] > 0
         window = 3
 
         def _advance_prefetch():
             while consume_order and len(prefetched) < window:
-                nxt = consume_order.pop(0)
-                prefetched[nxt] = prefetch_pool.submit(jobs[nxt].operands)
+                job = jobs[nxt := consume_order.pop(0)]
+                if job.a is None or job.w is None:
+                    stats.synthesized += 1
+                prefetched[unit[nxt]] = prefetch_pool.submit(job.operands)
 
         with span("profile.setup"):
             # Pay the one-time XLA/LLVM backend spin-up concurrently with the
@@ -829,74 +893,75 @@ def run_profile_batch(
                 -(-jobs[i].gemm_shape()[0] // 8) * 8 for i in members
             )
             for i in members:
+                if resolution[i] is not None:
+                    continue  # a cache hit keyed from the recipe memo
                 job = jobs[i]
-                try:
-                    with span("profile.synth_wait"):
-                        a, w = prefetched.pop(i).result()
-                except Exception as exc:
-                    # Malformed jobs are programming errors: typed, and
-                    # raised in EVERY mode (skipping them would hide bugs).
-                    raise classify_exception(
-                        exc, job=job.label(i), stage="schedule"
-                    ) from exc
-                _advance_prefetch()
-                with span("profile.check"):
-                    resolved = _resolve_backend(backend, a, w, job.rows, job.dataflow)
-                if use_cache:
-                    with span("profile.key"):
-                        key = _cache_key(
-                            a, w, job.rows, job.cols, job.b_h, job.b_v,
-                            (resolved, job.dataflow, "exact"),
-                        )
-                    hit, _source = _cache_get(key)
-                    if hit is not None:
-                        resolution[i] = ("cache", hit)
-                        stats.cache_hits += 1
-                        continue
-                with span("profile.check"):
-                    fused = resolved != "numpy" and _fused_eligible(job, a, w)
-                if not fused:
-                    if requested == "pallas" and resolved != "numpy":
-                        # match profile_gemm(backend="pallas"): loud
-                        # contract failure instead of a silent oracle detour
-                        from repro.kernels.activity_profile.ops import (
-                            profile_gemm_toggles,
-                        )
+                u = unit[i]
+                if u not in held:
+                    try:
+                        with span("profile.synth_wait"):
+                            held[u] = prefetched.pop(u).result()
+                    except Exception as exc:
+                        # Malformed jobs are programming errors: typed, and
+                        # raised in EVERY mode (skipping them would hide bugs).
+                        raise classify_exception(
+                            exc, job=job.label(i), stage="schedule"
+                        ) from exc
+                    _advance_prefetch()
+                a, w = held[u]
+                refs[u] -= 1
+                if not refs[u]:
+                    del held[u]
+                if facts[i] is None:
+                    # A recipe's first job scans and digests its operands;
+                    # its later jobs find the facts in the memo.
+                    f = _recipe_facts(job.recipe) if job.recipe is not None else None
+                    if f is None:
+                        f = _operand_facts(a, w)
+                        if job.recipe is not None:
+                            _remember_recipe(job.recipe, f)
+                    facts[i] = f
+                    looked_up[i] = _look_up(i)
+                f = facts[i]
+                resolved, store_key = looked_up[i]
+                if resolution[i] is None:
+                    fused = resolved != "numpy" and _fused_eligible(job, f.fits_fused)
+                    if not fused:
+                        if requested == "pallas" and resolved != "numpy":
+                            # match profile_gemm(backend="pallas"): loud
+                            # contract failure instead of a silent oracle detour
+                            from repro.kernels.activity_profile.ops import (
+                                profile_gemm_toggles,
+                            )
 
-                        profile_gemm_toggles(
-                            a, w, job.rows, job.cols, job.b_h, job.b_v,
-                            dataflow=job.dataflow,
+                            profile_gemm_toggles(
+                                a, w, job.rows, job.cols, job.b_h, job.b_v,
+                                dataflow=job.dataflow,
+                            )
+                        resolution[i] = ("serial", resolved)
+                        stats.serial_fallbacks += 1
+                        # the serial path profiles these operands at assembly
+                        job.a, job.w = a, w
+                        continue
+                    if job.dataflow == "OS":
+                        keys = _schedule_os_job(
+                            job, a, w, f, stream_bucket_map, stream_buckets,
+                            stream_pass_map, stats,
                         )
-                    resolution[i] = ("serial", resolved)
-                    stats.serial_fallbacks += 1
-                    continue
-                if job.dataflow == "OS":
-                    keys = _schedule_os_job(
-                        job, a, w, stream_bucket_map, stream_buckets,
-                        stream_pass_map, stats,
-                    )
-                    kind = "os_pass"
-                else:
-                    keys = _schedule_job(
-                        job, a, w, t_trim, bucket_map, buckets, pass_map, stats
-                    )
-                    kind = "pass"
-                # Record the operand statistics (and the content-cache store
-                # key) now and release lazy jobs' operands: the device holds
-                # the (int32) strip copies, so keeping every job's int64
-                # operands alive until collection would scale host memory
-                # with the whole workload.
-                store_key = None
-                if use_cache:
-                    with span("profile.key"):
-                        store_key = _cache_key(
-                            a, w, job.rows, job.cols, job.b_h, job.b_v,
-                            ("pallas", job.dataflow, "exact"),
+                        kind = "os_pass"
+                    else:
+                        keys = _schedule_job(
+                            job, a, w, f, t_trim, bucket_map, buckets, pass_map,
+                            stats,
                         )
-                resolution[i] = (
-                    kind,
-                    (keys, float(np.mean(a == 0)), int(a.size), store_key),
-                )
+                        kind = "pass"
+                    resolution[i] = (
+                        kind, (keys, f.zero_fraction, f.elements, store_key)
+                    )
+                # The device holds the (int32) strip copies and the facts
+                # carry the operand statistics, so a lazy job's int64
+                # operands go with its recipe's last reference instead of
+                # living until collection.
                 if job.make is not None:
                     job.a = job.w = None
             # Hand every program this shape class produced to a worker:

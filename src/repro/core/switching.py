@@ -60,7 +60,7 @@ import hashlib
 import os
 import warnings
 from collections import OrderedDict
-from typing import Iterable, Sequence
+from typing import Callable, Hashable, Iterable, Sequence
 
 import numpy as np
 
@@ -69,6 +69,7 @@ from repro.runtime.resilience import (
     ContractViolationError,
     ProfileDegradationWarning,
 )
+from repro.tracing import span
 
 __all__ = [
     "popcount",
@@ -352,7 +353,7 @@ def _fused_importable() -> bool:
         return False
 
 
-def _warn_numpy_fallback(reason: str) -> None:
+def _warn_numpy_fallback(reason: str, stacklevel: int) -> None:
     # warnings dedups by (message, location), so this surfaces once per run.
     # Typed (ProfileDegradationWarning subclasses RuntimeWarning) so callers
     # can filter degradations from generic runtime noise.
@@ -361,7 +362,7 @@ def _warn_numpy_fallback(reason: str) -> None:
         "slow numpy oracle. Exact full-stream profiling is the default — "
         "pass max_tiles/max_stream to bound large workloads.",
         ProfileDegradationWarning,
-        stacklevel=4,
+        stacklevel=stacklevel,
     )
 
 
@@ -372,27 +373,52 @@ def _resolve_backend(
     rows: int,
     dataflow: str = "WS",
 ) -> str:
+    def fits() -> bool:
+        from repro.kernels.activity_profile.ops import operands_fit_fused
+
+        return operands_fit_fused(a, w)
+
+    return _resolve_backend_fit(
+        backend, a.shape, w.shape, rows, dataflow, fits, stacklevel=5
+    )
+
+
+def _resolve_backend_fit(
+    backend: str | None,
+    a_shape: tuple[int, int],
+    w_shape: tuple[int, int],
+    rows: int,
+    dataflow: str,
+    fits: Callable[[], bool],
+    *,
+    stacklevel: int,
+) -> str:
+    """``_resolve_backend`` from the operands' shapes and ``fits()``, whether
+    they fit the fused engine's int16 contract (asked only when "auto" has
+    to know): the same choice and the same fallback warnings, so a caller
+    that knows the operands' facts resolves without their bytes.
+    ``stacklevel`` goes to ``warnings.warn`` (frame 2 is this function):
+    each caller points the warning at the code that asked for a profile."""
     backend = backend if backend is not None else DEFAULT_BACKEND
     if backend == "auto":
         if not _fused_importable():
-            _warn_numpy_fallback("jax not importable")
+            _warn_numpy_fallback("jax not importable", stacklevel)
             return "numpy"
         from repro.kernels.activity_profile.ops import (
             MAX_FUSED_K,
             MAX_FUSED_LANES,
             MAX_FUSED_ROWS,
-            operands_fit_fused,
         )
 
         if dataflow == "OS":
-            dims_ok = max(a.shape[0], w.shape[1]) < MAX_FUSED_LANES
+            dims_ok = max(a_shape[0], w_shape[1]) < MAX_FUSED_LANES
         else:
-            dims_ok = a.shape[1] + rows < MAX_FUSED_K and rows < MAX_FUSED_ROWS
+            dims_ok = a_shape[1] + rows < MAX_FUSED_K and rows < MAX_FUSED_ROWS
         if not dims_ok:
-            _warn_numpy_fallback("GEMM/array dims beyond fused-engine bounds")
+            _warn_numpy_fallback("GEMM/array dims beyond fused-engine bounds", stacklevel)
             return "numpy"
-        if not operands_fit_fused(a, w):
-            _warn_numpy_fallback("operands wider than int16")
+        if not fits():
+            _warn_numpy_fallback("operands wider than int16", stacklevel)
             return "numpy"
         return "pallas"
     if backend not in ("numpy", "pallas"):
@@ -410,6 +436,12 @@ def _resolve_backend(
 # is enabled by ``configure_profile_store(path)`` or ``$REPRO_PROFILE_STORE``
 # and stays off otherwise (in-process behavior is then exactly the old
 # memory-only cache).
+#
+# A key hashes the operands' digests, never their bytes directly, so jobs
+# that declare a recipe (``ProfileJob.recipe``) reach the SAME key through
+# the recipe memo below: the batch pipeline synthesizes, range-checks and
+# digests each recipe once and keys every later job of it from the memo,
+# with no operands in hand.
 
 _KEY_VERSION = "v4"  # also the on-disk store's schema-version directory
 
@@ -425,10 +457,12 @@ _PROFILE_STORE_RESOLVED = False
 
 
 def clear_profile_cache() -> None:
-    """Drop the in-memory cache + reset its counters (the on-disk store, if
-    configured, is NOT touched — it exists to outlive process state)."""
+    """Drop the in-memory cache and the recipe memo + reset their counters
+    (the on-disk store, if configured, is NOT touched — it exists to outlive
+    process state)."""
     global _THRASH_WARNED
     _PROFILE_CACHE.clear()
+    _RECIPE_FACTS.clear()
     for k in _PROFILE_CACHE_STATS:
         _PROFILE_CACHE_STATS[k] = 0
     _THRASH_WARNED = False
@@ -456,6 +490,8 @@ def set_profile_cache_capacity(capacity: int) -> int:
     while len(_PROFILE_CACHE) > _PROFILE_CACHE_CAPACITY:
         _PROFILE_CACHE.popitem(last=False)
         _PROFILE_CACHE_STATS["evictions"] += 1
+    while len(_RECIPE_FACTS) > _PROFILE_CACHE_CAPACITY:
+        _RECIPE_FACTS.popitem(last=False)
     return prev
 
 
@@ -525,16 +561,20 @@ def _note_batch_stores(n_stored: int) -> None:
     )
 
 
-def _operand_digest(arr: np.ndarray) -> bytes:
+def _operand_digest(arr: np.ndarray, bounds: tuple[int, int] | None = None) -> bytes:
     """Value-canonical sha256 of one operand matrix.
 
     int16-range data (the common case) hashes at 2 bytes/element instead of
     the upcast 8, and equal values hit the same digest regardless of input
-    dtype. Also used by the batch pipeline's cross-geometry pass reuse.
+    dtype. ``bounds`` is ``(min, max)`` of a non-empty ``arr`` when the
+    caller has already scanned it. Also used by the batch pipeline's
+    cross-geometry pass reuse.
     """
     h = hashlib.sha256()
-    if arr.size and -32768 <= int(arr.min()) and int(arr.max()) <= 32767:
-        arr = arr.astype(np.int16)
+    if arr.size:
+        lo, hi = bounds if bounds is not None else (int(arr.min()), int(arr.max()))
+        if -32768 <= lo and hi <= 32767:
+            arr = arr.astype(np.int16)
     h.update(arr.dtype.str.encode())
     h.update(np.ascontiguousarray(arr).tobytes())
     return h.digest()
@@ -549,13 +589,90 @@ def _cache_key(
     adds the lane-detail flag to the plan (lane-resolved profiles carry
     strictly more data than aggregate ones and must not alias them; it also
     retires any pre-lane "v3" entry shape)."""
+    return _digest_cache_key(
+        a.shape, w.shape, _operand_digest(a), _operand_digest(w),
+        rows, cols, b_h, b_v, mode,
+    )
+
+
+def _digest_cache_key(
+    a_shape, w_shape, a_digest: bytes, w_digest: bytes, rows, cols, b_h, b_v,
+    mode: tuple,
+) -> bytes:
+    """``_cache_key`` from the operands' shapes and ``_operand_digest``s:
+    the same bytes, with no operand in hand."""
     h = hashlib.sha256()
     h.update(
-        repr((_KEY_VERSION, a.shape, w.shape, rows, cols, b_h, b_v, mode)).encode()
+        repr((_KEY_VERSION, a_shape, w_shape, rows, cols, b_h, b_v, mode)).encode()
     )
-    for arr in (a, w):
-        h.update(_operand_digest(arr))
+    h.update(a_digest)
+    h.update(w_digest)
     return h.digest()
+
+
+@dataclasses.dataclass(frozen=True)
+class OperandFacts:
+    """What the batch pipeline needs of one (a, w) operand pair besides its
+    bytes: shapes and digests (every content and pass key), whether both
+    fit the fused engine's int16 contract, and ``a``'s zero fraction and
+    size (the profile's input statistics)."""
+
+    a_shape: tuple[int, int]
+    w_shape: tuple[int, int]
+    a_digest: bytes
+    w_digest: bytes
+    fits_fused: bool
+    zero_fraction: float
+    elements: int
+
+    def cache_key(self, rows, cols, b_h, b_v, mode: tuple) -> bytes:
+        return _digest_cache_key(
+            self.a_shape, self.w_shape, self.a_digest, self.w_digest,
+            rows, cols, b_h, b_v, mode,
+        )
+
+
+def _operand_facts(a: np.ndarray, w: np.ndarray) -> OperandFacts:
+    """One range scan and one digest per operand (int64 operands of the
+    fused pipeline, which has jax imported)."""
+    from repro.kernels.activity_profile.ops import INT16_SAFE_MAX
+
+    with span("profile.check"):
+        bounds = [(int(x.min()), int(x.max())) if x.size else None for x in (a, w)]
+        fits = all(
+            b is None or (-INT16_SAFE_MAX <= b[0] and b[1] <= INT16_SAFE_MAX)
+            for b in bounds
+        )
+        zero_fraction = float(np.mean(a == 0))
+    with span("profile.key"):
+        a_digest, w_digest = (_operand_digest(x, b) for x, b in zip((a, w), bounds))
+    return OperandFacts(
+        a.shape, w.shape, a_digest, w_digest, fits, zero_fraction, int(a.size)
+    )
+
+
+# --- operand recipe memo ----------------------------------------------------
+# A lazy job may declare a recipe: a hashable of exactly what its ``make``
+# reads (``workloads.gemm_job``), so equal recipes synthesize byte-equal
+# operands.  The memo keeps each recipe's ``OperandFacts``: the pipeline
+# synthesizes, scans and digests a recipe once, and keys every later job of
+# it without synthesizing.  LRU, bounded by the profile cache's capacity,
+# emptied with it; filled by the batch pipeline's calling thread only.
+
+_RECIPE_FACTS: OrderedDict[Hashable, OperandFacts] = OrderedDict()
+
+
+def _recipe_facts(recipe: Hashable) -> OperandFacts | None:
+    facts = _RECIPE_FACTS.get(recipe)
+    if facts is not None:
+        _RECIPE_FACTS.move_to_end(recipe)
+    return facts
+
+
+def _remember_recipe(recipe: Hashable, facts: OperandFacts) -> None:
+    _RECIPE_FACTS[recipe] = facts
+    while len(_RECIPE_FACTS) > _PROFILE_CACHE_CAPACITY:
+        _RECIPE_FACTS.popitem(last=False)
 
 
 def _cache_get(key: bytes) -> tuple[ActivityProfile | None, str | None]:

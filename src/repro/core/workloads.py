@@ -13,6 +13,7 @@ Two sources:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Sequence
 
@@ -227,6 +228,9 @@ def gemm_job(
      1/sqrt(K)-scaled Gaussians, quantized to ``bits`` — the recipe of
     ``examples/sa_power_llm.py``. ``clip`` bounds the profiled slice of
     very large GEMMs (toggle *rates* converge long before full LLM dims).
+    The job's ``recipe`` is (generator tag, clipped m, k, n, density, seed,
+    bits), so jobs of one operand class across activity classes share one
+    synthesis in the batch pipeline.
     """
     from repro.core.pipeline import ProfileJob
 
@@ -234,25 +238,36 @@ def gemm_job(
     if clip is not None:
         m, k, n = min(m, clip[0]), min(k, clip[1]), min(n, clip[2])
     bv = b_v if b_v is not None else _default_b_v(bits, rows, dataflow)
-
-    def make():
-        rng = np.random.default_rng(seed)
-        a_f = np.maximum(rng.normal(0.0, 1.0, size=(m, k)), 0.0)
-        if density is not None:
-            a_f = np.where(rng.random((m, k)) < density, a_f, 0.0)
-        w_f = rng.normal(0.0, 1.0 / np.sqrt(k), size=(k, n))
-        return quantize_symmetric(a_f, bits).values, quantize_symmetric(w_f, bits).values
-
+    # The operands depend on nothing else (not rows, cols, b_v or the
+    # dataflow): ``make`` reads only the recipe, so the two cannot drift.
+    recipe = (_GEMM_RECIPE, m, k, n, density, seed, bits)
     return ProfileJob(
         rows=rows,
         cols=cols,
         b_h=bits,
         b_v=bv,
-        make=make,
+        make=functools.partial(_synth_gemm_operands, recipe),
         shape=(m, k, n),
         name=gemm.name,
         dataflow=dataflow,
+        recipe=recipe,
     )
+
+
+# Generator tag of ``gemm_job`` recipes; bump its version whenever
+# ``_synth_gemm_operands`` changes what it builds from a recipe.
+_GEMM_RECIPE = ("gemm_job", 1)
+
+
+def _synth_gemm_operands(recipe: tuple) -> tuple[np.ndarray, np.ndarray]:
+    """The int operands of one ``gemm_job`` recipe."""
+    _, m, k, n, density, seed, bits = recipe
+    rng = np.random.default_rng(seed)
+    a_f = np.maximum(rng.normal(0.0, 1.0, size=(m, k)), 0.0)
+    if density is not None:
+        a_f = np.where(rng.random((m, k)) < density, a_f, 0.0)
+    w_f = rng.normal(0.0, 1.0 / np.sqrt(k), size=(k, n))
+    return quantize_symmetric(a_f, bits).values, quantize_symmetric(w_f, bits).values
 
 
 def profile_network(

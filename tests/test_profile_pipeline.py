@@ -2,7 +2,11 @@
 batched engine vs the per-GEMM engine and the numpy counts oracle on ragged
 job sets, cache-hit accounting across a batch, geometry-sweep pass reuse,
 device sharding, serial fallbacks, and the workload-level profile_network
-wrapper. The Pallas task kernel runs under interpret=True for CPU CI."""
+wrapper, and operand recipes (one synthesis per recipe, cache hits keyed
+from the recipe memo). The Pallas task kernel runs under interpret=True for
+CPU CI."""
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -13,9 +17,17 @@ from repro.core.switching import (
     profile_cache_info,
     profile_gemm,
     profile_gemms,
+    set_profile_cache_capacity,
 )
-from repro.core.workloads import ConvLayer, conv_layer_job, profile_network
+from repro.core.workloads import (
+    ConvLayer,
+    Gemm,
+    conv_layer_job,
+    gemm_job,
+    profile_network,
+)
 from repro.kernels.activity_profile.ref import profile_gemm_toggles_ref
+from repro.runtime.resilience import CacheThrashWarning
 
 RNG = np.random.default_rng(0)
 
@@ -373,3 +385,218 @@ def test_os_profile_network_matches_serial_layers():
         assert _counts(batched[i]) == profile_gemm_toggles_ref(
             a, w, 16, 8, job.b_h, job.b_v, dataflow="OS"
         )
+
+
+# ---------------------------------------------------------------------------
+# Operand recipes: one synthesis per recipe, memo-keyed cache hits
+# ---------------------------------------------------------------------------
+
+RECIPE_BASE = dict(m=24, k=40, n=16, density=0.5, seed=5, bits=8)
+
+
+def _recipe_job(rows=8, cols=8, dataflow="WS", **over):
+    p = {**RECIPE_BASE, **over}
+    return gemm_job(
+        Gemm("g", p["m"], p["k"], p["n"]), rows=rows, cols=cols, bits=p["bits"],
+        seed=p["seed"], density=p["density"], clip=None, dataflow=dataflow,
+    )
+
+
+@pytest.mark.parametrize(
+    "field,value",
+    [("m", 25), ("k", 41), ("n", 17), ("density", 0.6), ("seed", 6), ("bits", 6)],
+)
+def test_gemm_recipe_fixes_the_operands(field, value):
+    """Equal recipes build byte-equal operands whatever the geometry and
+    dataflow; changing any one recipe field changes them."""
+    base = _recipe_job()
+    twin = _recipe_job(rows=16, cols=4, dataflow="OS")
+    assert twin.recipe == base.recipe and twin.b_v != base.b_v
+    a0, w0 = base.operands()
+    a1, w1 = twin.operands()
+    assert a0.tobytes() == a1.tobytes() and w0.tobytes() == w1.tobytes()
+    changed = _recipe_job(**{field: value})
+    assert changed.recipe != base.recipe
+    a2, w2 = changed.operands()
+    assert (a2.shape, w2.shape) != (a0.shape, w0.shape) or not (
+        np.array_equal(a2, a0) and np.array_equal(w2, w0)
+    )
+
+
+# three activity classes (two WS depths and OS) x three operand recipes
+RECIPE_CLASSES = [(8, "WS"), (16, "WS"), (8, "OS")]
+RECIPE_SHAPES = [dict(m=24, k=40, n=16), dict(m=9, k=33, n=20, density=None), dict(m=40, k=16, n=8, seed=2)]
+
+
+def _recipe_batch(make_calls=None):
+    """Class-major recipe jobs, as ``design_gemm_jobs`` emits them; each
+    ``make`` call is appended to ``make_calls`` when given."""
+    jobs = [
+        _recipe_job(rows=rows, dataflow=df, **shape)
+        for rows, df in RECIPE_CLASSES
+        for shape in RECIPE_SHAPES
+    ]
+    if make_calls is not None:
+        for job in jobs:
+            job.make = _counted(job.make, job.recipe, make_calls)
+    return jobs
+
+
+def _counted(make, recipe, calls):
+    def counted_make():
+        calls.append(recipe)
+        return make()
+
+    return counted_make
+
+
+def test_recipe_batch_synthesizes_each_recipe_once():
+    clear_profile_cache()
+    calls = []
+    jobs = _recipe_batch(calls)
+    profiles, stats = run_profile_batch(jobs)
+    assert stats.jobs == 9 and stats.cache_hits == 0 and stats.recipe_hits == 0
+    assert stats.synthesized == len(RECIPE_SHAPES) == len(calls) == len(set(calls))
+    for job, p in zip(_recipe_batch(), profiles):
+        a, w = job.operands()
+        s = profile_gemm(
+            a, w, job.rows, job.cols, job.b_h, job.b_v,
+            dataflow=job.dataflow, backend="pallas", use_cache=False,
+        )
+        assert _counts(p) == _counts(s) == profile_gemm_toggles_ref(
+            a, w, job.rows, job.cols, job.b_h, job.b_v, dataflow=job.dataflow
+        )
+        assert (p.input_zero_fraction, p.input_elements) == (
+            s.input_zero_fraction, s.input_elements
+        )
+    clear_profile_cache()
+
+
+def test_warm_recipe_batch_synthesizes_nothing():
+    clear_profile_cache()
+    first, _ = run_profile_batch(_recipe_batch())
+    calls = []
+    profiles, stats = run_profile_batch(_recipe_batch(calls))
+    assert calls == [] and stats.synthesized == 0
+    assert stats.recipe_hits == stats.cache_hits == stats.jobs == 9
+    assert stats.passes == stats.buckets == 0
+    assert profiles == first
+    clear_profile_cache()
+
+
+def test_clearing_the_cache_empties_the_recipe_memo():
+    clear_profile_cache()
+    run_profile_batch(_recipe_batch())
+    clear_profile_cache()
+    calls = []
+    _, stats = run_profile_batch(_recipe_batch(calls))
+    assert stats.recipe_hits == stats.cache_hits == 0
+    assert stats.synthesized == len(calls) == len(RECIPE_SHAPES)
+    clear_profile_cache()
+
+
+def test_recipe_memo_bounded_by_the_cache_capacity():
+    from repro.core import switching
+
+    clear_profile_cache()
+    prev = set_profile_cache_capacity(2)
+    try:
+        with pytest.warns(CacheThrashWarning):
+            run_profile_batch(_recipe_batch())
+        assert len(switching._RECIPE_FACTS) == 2
+        # the memo keys the two newest recipes' jobs without operands; the
+        # evicted profiles still miss and are profiled again, bit-exact
+        calls = []
+        profiles, stats = run_profile_batch(_recipe_batch(calls))
+        assert stats.recipe_hits == 2 * len(RECIPE_CLASSES)
+        assert stats.cache_hits == 2 and stats.synthesized == len(set(calls))
+        for job, p in zip(_recipe_batch(), profiles):
+            assert _counts(p) == profile_gemm_toggles_ref(
+                *job.operands(), job.rows, job.cols, job.b_h, job.b_v,
+                dataflow=job.dataflow,
+            )
+    finally:
+        set_profile_cache_capacity(prev)
+        clear_profile_cache()
+
+
+@pytest.mark.parametrize("kind", ["conv", "eager", "stripped"])
+def test_jobs_without_a_recipe_unchanged(kind):
+    """Jobs without a recipe (conv layers, eager operands, recipe jobs with
+    the recipe taken off) are synthesized and keyed per job, as before the
+    memo: the same scheduler counts and profiles as their recipe twins."""
+    if kind == "conv":
+        layers = [
+            ConvLayer("t1", k=1, h=5, w=5, c=40, m=9, input_density=0.5),
+            ConvLayer("t2", k=3, h=3, w=3, c=7, m=17, input_density=0.4),
+        ]
+
+        def batch():
+            return [
+                conv_layer_job(layer, rows=rows, cols=8, bits=8, seed=i, dataflow=df)
+                for rows, df in RECIPE_CLASSES
+                for i, layer in enumerate(layers)
+            ]
+    else:
+        def batch():
+            jobs = [dataclasses.replace(j, recipe=None) for j in _recipe_batch()]
+            if kind == "eager":
+                for job in jobs:
+                    job.operands()
+                    job.make = None
+            return jobs
+
+    clear_profile_cache()
+    jobs = batch()
+    assert all(job.recipe is None for job in jobs)
+    profiles, stats = run_profile_batch(jobs)
+    built = 0 if kind == "eager" else len(jobs)
+    assert stats.recipe_hits == 0 and stats.synthesized == built
+    for job, p in zip(batch(), profiles):
+        a, w = job.operands()
+        assert _counts(p) == profile_gemm_toggles_ref(
+            a, w, job.rows, job.cols, job.b_h, job.b_v, dataflow=job.dataflow
+        )
+    warm, warm_stats = run_profile_batch(batch())
+    assert warm == profiles
+    assert warm_stats.cache_hits == len(jobs) and warm_stats.recipe_hits == 0
+    assert warm_stats.synthesized == built  # keyed from the operands again
+    if kind != "conv":
+        clear_profile_cache()
+        twin, twin_stats = run_profile_batch(_recipe_batch())
+        assert twin == profiles
+        skip = {"synthesized", "failure_report"}
+        assert {k: v for k, v in twin_stats.as_dict().items() if k not in skip} == {
+            k: v for k, v in stats.as_dict().items() if k not in skip
+        }
+    clear_profile_cache()
+
+
+def test_digest_key_equals_operand_key_and_hits_the_store(tmp_path):
+    """A key built from the memo's digests is the operand-built key, byte
+    for byte: a store entry written by a job without a recipe is hit by its
+    recipe twin."""
+    from repro.core.switching import (
+        _cache_key,
+        _operand_facts,
+        configure_profile_store,
+    )
+
+    a, w = _recipe_job().operands()
+    facts = _operand_facts(a, w)
+    for mode in [("pallas", "WS", "exact"), ("numpy", "OS", "exact"), ("pallas", "WS", "exact", "lanes")]:
+        assert facts.cache_key(8, 4, 8, 23, mode) == _cache_key(a, w, 8, 4, 8, 23, mode)
+
+    clear_profile_cache()
+    configure_profile_store(tmp_path / "store")
+    try:
+        plain = dataclasses.replace(_recipe_job(), recipe=None)
+        (p0,), s0 = run_profile_batch([plain])
+        assert s0.cache_hits == 0 and s0.synthesized == 1
+        clear_profile_cache()  # memory only: the store keeps the entry
+        (p1,), s1 = run_profile_batch([_recipe_job()])
+        assert s1.store_hits == s1.cache_hits == 1 and s1.synthesized == 1
+        assert p1 == p0
+    finally:
+        configure_profile_store(None)
+        clear_profile_cache()

@@ -159,6 +159,45 @@ def test_codesign_spans_and_profile_stats(tmp_path, monkeypatch):
     assert np.isfinite(res.j_per_token)
 
 
+def test_synthesize_span_once_per_recipe(tmp_path, monkeypatch):
+    """In one answer the prefetch worker builds each operand recipe once:
+    ``repro.profile.synthesize`` opens once per distinct recipe of the
+    batch, not once per job."""
+    import jax
+
+    import repro.core.pipeline as pipeline
+    from repro.core.design_space import DesignSpace
+    from repro.core.switching import clear_profile_cache
+    from repro.serving import codesign
+
+    recipes = []
+    real = pipeline.run_profile_batch
+
+    def spy(jobs, *args, **kwargs):
+        jobs = list(jobs)
+        recipes.append([job.recipe for job in jobs])
+        return real(jobs, *args, **kwargs)
+
+    monkeypatch.setattr(pipeline, "run_profile_batch", spy)
+    clear_profile_cache()
+    space = DesignSpace(
+        rows=(8, 16), cols=(8,), input_bits=(8,), dataflows=("WS", "OS"),
+        bus_invert=(False,),
+    )
+    with jax.profiler.trace(str(tmp_path)):
+        res = codesign(
+            "mixtral_8x7b", "decode_heavy", space=space, layouts=("uniform",),
+            clip=(16, 64, 32),
+        )
+    (batch,) = recipes
+    distinct = len(set(batch))
+    assert None not in batch and len(batch) == 3 * distinct  # 3 activity classes
+    names = [n for _, _, n, _, _ in _trace_events(tmp_path)]
+    assert names.count("repro.profile.synthesize") == distinct
+    assert res.profile_stats.synthesized == distinct
+    assert res.profile_stats.recipe_hits == 0
+
+
 @pytest.mark.parametrize("dispatched", [True, False])
 def test_collect_span_only_when_dispatched(tmp_path, dispatched):
     """A batch served wholly from the cache opens no ``profile.collect``."""
