@@ -12,7 +12,7 @@ import dataclasses
 import importlib
 from typing import Literal
 
-MixerKind = Literal["attn", "mamba", "mlstm", "slstm"]
+MixerKind = Literal["attn", "mla", "mamba", "mlstm", "slstm"]
 MlpKind = Literal["dense", "moe", "none"]
 
 
@@ -36,6 +36,19 @@ class ArchConfig:
     rope_theta: float = 10000.0
     mrope_sections: tuple[int, int, int] = (16, 24, 24)
     attn_chunk: int = 1024  # dense attention below this seq, blockwise above
+
+    # multi-head latent attention (HF DeepSeek names; ``head_dim`` is
+    # ``qk_nope_head_dim``): queries and keys/values pass through low-rank
+    # latents of these ranks; a 64-dim rotary part rides beside the latent
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # leading dense layers before the periodic pattern (HF
+    # ``first_k_dense_replace``): each runs the pattern's first mixer with a
+    # dense MLP of width ``d_ff``
+    first_k_dense: int = 0
 
     # MoE
     num_experts: int = 1
@@ -82,15 +95,26 @@ class ArchConfig:
     mamba_state_dtype: str = "float32"
 
     def __post_init__(self):
-        if self.n_layers % len(self.stage_pattern):
+        # configs read from JSON carry the pattern's pairs as lists
+        object.__setattr__(
+            self, "stage_pattern", tuple(tuple(p) for p in self.stage_pattern)
+        )
+        if not 0 <= self.first_k_dense < self.n_layers:
             raise ValueError(
-                f"{self.name}: n_layers {self.n_layers} not a multiple of "
-                f"stage pattern length {len(self.stage_pattern)}"
+                f"{self.name}: first_k_dense {self.first_k_dense} not in "
+                f"[0, n_layers {self.n_layers})"
+            )
+        if (self.n_layers - self.first_k_dense) % len(self.stage_pattern):
+            raise ValueError(
+                f"{self.name}: n_layers {self.n_layers} less first_k_dense "
+                f"{self.first_k_dense} not a multiple of stage pattern length "
+                f"{len(self.stage_pattern)}"
             )
 
     @property
     def n_stages(self) -> int:
-        return self.n_layers // len(self.stage_pattern)
+        """Repetitions of ``stage_pattern`` after the leading dense layers."""
+        return (self.n_layers - self.first_k_dense) // len(self.stage_pattern)
 
     @property
     def dt_rank(self) -> int:
@@ -100,14 +124,21 @@ class ArchConfig:
         return dataclasses.replace(self, param_dtype=param, compute_dtype=compute)
 
     def reduced(self) -> "ArchConfig":
-        """Same-family tiny config for CPU smoke tests (one stage)."""
+        """Same-family tiny config for CPU smoke tests (one stage, and one
+        leading dense layer if the config has any)."""
         heads = min(self.num_heads, 4)
         kv = max(1, min(self.num_kv_heads, heads))
         while heads % kv:
             kv -= 1
+        lead = min(self.first_k_dense, 1)
         return dataclasses.replace(
             self,
-            n_layers=len(self.stage_pattern),
+            n_layers=len(self.stage_pattern) + lead,
+            first_k_dense=lead,
+            q_lora_rank=self.q_lora_rank and 48,
+            kv_lora_rank=self.kv_lora_rank and 32,
+            qk_rope_head_dim=self.qk_rope_head_dim and 16,
+            v_head_dim=self.v_head_dim and 24,
             d_model=128,
             num_heads=heads,
             num_kv_heads=kv,
@@ -165,6 +196,7 @@ ARCH_IDS = (
     "qwen3_8b",
     "llama4_maverick_400b",
     "mixtral_8x7b",
+    "deepseek_v3",
 )
 
 # external ids (assignment spelling) -> module ids
@@ -179,6 +211,7 @@ ALIASES = {
     "qwen3-8b": "qwen3_8b",
     "llama4-maverick-400b-a17b": "llama4_maverick_400b",
     "mixtral-8x7b": "mixtral_8x7b",
+    "deepseek-v3": "deepseek_v3",
 }
 
 

@@ -35,10 +35,26 @@ Axes = dict
 
 _MIXER_INIT = {
     "attn": blocks.attn_init,
+    "mla": blocks.mla_init,
     "mamba": ssm.mamba_init,
     "mlstm": xlstm.mlstm_init,
     "slstm": xlstm.slstm_init,
 }
+
+
+def builds(cfg) -> bool:
+    """Whether forward, loss, cache and decode build ``cfg``: every mixer
+    but latent attention, and no leading dense layers. ``init_params``
+    (hence ``count_params_analytic``) covers every config."""
+    return not cfg.first_k_dense and all(m != "mla" for m, _ in cfg.stage_pattern)
+
+
+def _require_builds(cfg):
+    if not builds(cfg):
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention and leading dense layers have no "
+            "forward in this stack"
+        )
 
 
 def init_params(cfg, key: jax.Array) -> tuple[Params, Axes]:
@@ -87,6 +103,17 @@ def init_params(cfg, key: jax.Array) -> tuple[Params, Axes]:
         stages_a[f"block{i}"] = ba
     p["stages"] = stages_p
     a["stages"] = stages_a
+
+    if cfg.first_k_dense:  # leading layers: the first mixer, a dense MLP
+        n = cfg.first_k_dense
+        lk = jax.random.split(jax.random.fold_in(k_stages, len(cfg.stage_pattern)), 2)
+        lp, la = {}, {}
+        lp["ln1"], la["ln1"] = ones_param((cfg.d_model,), ("embed",), stack=n)
+        lp["mixer"], la["mixer"] = _MIXER_INIT[cfg.stage_pattern[0][0]](lk[0], cfg, n)
+        lp["ln2"], la["ln2"] = ones_param((cfg.d_model,), ("embed",), stack=n)
+        lp["mlp"], la["mlp"] = blocks.mlp_init(lk[1], cfg, n)
+        p["lead"] = lp
+        a["lead"] = la
 
     if dtype != jnp.float32:
         p = jax.tree.map(
@@ -182,6 +209,7 @@ def hidden_forward(
     positions: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Embed + stage stack + final norm. Returns (hidden (B, S, D), aux)."""
+    _require_builds(cfg)
     dtype = jnp.dtype(cfg.compute_dtype)
     b, s = tokens.shape[0], tokens.shape[1]
     if positions is None:
@@ -320,6 +348,7 @@ def cache_len_for(cfg, seq_len: int) -> int:
 
 
 def init_cache(cfg, batch: int, seq_len: int, dtype=None) -> tuple[Params, Axes]:
+    _require_builds(cfg)
     dtype = dtype or jnp.dtype(cfg.compute_dtype)
     clen = cache_len_for(cfg, seq_len)
     cache: dict[str, Any] = {}
@@ -346,6 +375,7 @@ def decode_step(
     pos: jnp.ndarray,  # scalar int32: position index of this token
 ) -> tuple[jnp.ndarray, Params]:
     """One decoding step for the whole stack. Returns (logits (B, V[, K]), cache)."""
+    _require_builds(cfg)
     dtype = jnp.dtype(cfg.compute_dtype)
     x = _embed(cfg, params, tokens, dtype)
     x = shard_hint(x, "batch", "seq", "embed")

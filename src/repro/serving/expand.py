@@ -2,8 +2,8 @@
 
 Layer 1 of the serving subsystem (DESIGN.md §Serving-workloads).  A model
 config from ``repro.configs.registry`` is walked block by block — attention,
-Mamba, m/sLSTM, dense/MoE MLP, LM head — into the concrete GEMM shapes one
-forward step executes under a serving regime:
+latent attention, Mamba, m/sLSTM, dense/MoE MLP, LM head — into the concrete
+GEMM shapes one forward step executes under a serving regime:
 
   * ``prefill``: M = batch * seq_len tokens flow through every projection;
   * ``decode``:  M = the decode-step token count, derived from the SAME
@@ -11,13 +11,31 @@ forward step executes under a serving regime:
     axis == 1), so the serving expansion and ``decode_batch_specs`` can
     never drift apart.
 
-MoE routing sparsity (top_k / num_experts) becomes the per-expert effective
-batch: each of the E experts sees ``round(tokens * top_k / E)`` rows, so the
-expansion prices exactly the active-parameter GEMM work, with the router and
-any shared experts at the full token batch.  Attention score/context
-products (QK^T, PV) are cache-shaped dynamic-by-dynamic products served by
-the flash-attention kernel, not stationary-weight GEMMs, and are out of
-scope here — same contract as ``core.workloads.gemms_for_arch``.
+Leading dense layers (``cfg.first_k_dense``) are emitted once, with their
+count, before the periodic walk of ``stage_pattern``; each runs the
+pattern's first mixer and a dense MLP of width ``d_ff``.
+
+Multi-head latent attention (``mla``, DeepSeek-V2 §2.1) is the one mixer
+whose GEMMs depend on the regime.  Both regimes share the down projections
+``mla.q_a`` (d -> q latent), ``mla.kv_a`` (d -> kv latent + rope dims) and
+the up projection ``mla.q_b`` (q latent -> heads x (nope + rope)) and
+``mla.o``.  Prefill runs the expanded form: ``mla.kv_b`` lifts the kv
+latent to per-head keys and values (kv latent -> heads x (nope + v)).
+Decode runs the absorbed form over the latent cache: no ``kv_b`` over the
+cache, but two per-head stationary products, ``mla.uk`` (q_nope against
+W_UK, nope -> kv latent) and ``mla.uv`` (latent context against W_UV, kv
+latent -> v), each repeated once per head.
+
+Routed experts follow the exact even split of the step's ``t * top_k``
+expert rows over the E experts: min(E, t*top_k) experts are active; when
+t*top_k <= E each active expert gets one row, otherwise r = t*top_k mod E
+experts get q + 1 rows and E - r get q, with q = t*top_k // E.  So the
+priced routed rows equal t*top_k exactly, and the router and any shared
+experts run at the full token batch.  Attention score/context products
+(QK^T, PV, and their latent forms) are cache-shaped dynamic-by-dynamic
+products served by the flash-attention kernel, not stationary-weight GEMMs,
+and are out of scope here — same contract as
+``core.workloads.gemms_for_arch``.
 
 Every emitted ``ServingGemm`` carries a ``count`` multiplicity (layers x
 heads x experts ...) so identical shapes collapse to one entry, and an
@@ -117,7 +135,7 @@ def routing_sparsity(cfg) -> float:
 # ---------------------------------------------------------------------------
 
 
-def _attn_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
+def _attn_gemms(cfg, t: int, regime: str) -> list[tuple[str, Gemm, int, float | None]]:
     d = cfg.d_model
     q_out = cfg.num_heads * cfg.head_dim
     kv_out = cfg.num_kv_heads * cfg.head_dim
@@ -129,7 +147,26 @@ def _attn_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
     ]
 
 
-def _mamba_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
+def _mla_gemms(cfg, t: int, regime: str) -> list[tuple[str, Gemm, int, float | None]]:
+    d = cfg.d_model
+    h = cfg.num_heads
+    nope, rope, dv = cfg.head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q_lat, kv_lat = cfg.q_lora_rank, cfg.kv_lora_rank
+    out = [
+        ("mla.q_a", Gemm("q_a", t, d, q_lat), 1, None),
+        ("mla.q_b", Gemm("q_b", t, q_lat, h * (nope + rope)), 1, None),
+        ("mla.kv_a", Gemm("kv_a", t, d, kv_lat + rope), 1, None),
+    ]
+    if regime == "prefill":
+        out.append(("mla.kv_b", Gemm("kv_b", t, kv_lat, h * (nope + dv)), 1, None))
+    else:  # absorbed: W_UK on q_nope, W_UV on the latent context, per head
+        out.append(("mla.uk", Gemm("uk", t, nope, kv_lat), h, None))
+        out.append(("mla.uv", Gemm("uv", t, kv_lat, dv), h, None))
+    out.append(("mla.o", Gemm("o", t, h * dv, d), 1, None))
+    return out
+
+
+def _mamba_gemms(cfg, t: int, regime: str) -> list[tuple[str, Gemm, int, float | None]]:
     d = cfg.d_model
     di = cfg.mamba_expand * d
     n = cfg.mamba_d_state
@@ -144,7 +181,7 @@ def _mamba_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
     ]
 
 
-def _mlstm_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
+def _mlstm_gemms(cfg, t: int, regime: str) -> list[tuple[str, Gemm, int, float | None]]:
     d = cfg.d_model
     di = int(cfg.xlstm_proj_factor * d)
     h = cfg.num_heads
@@ -158,7 +195,7 @@ def _mlstm_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
     ]
 
 
-def _slstm_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
+def _slstm_gemms(cfg, t: int, regime: str) -> list[tuple[str, Gemm, int, float | None]]:
     d = cfg.d_model
     h = cfg.num_heads
     dh = d // h
@@ -185,20 +222,26 @@ def _dense_mlp_gemms(
     return out
 
 
+def expert_rows(t: int, top_k: int, num_experts: int) -> list[tuple[int, int]]:
+    """The exact even split of ``t * top_k`` routed rows over the experts:
+    ``[(rows, experts), ...]``, larger share first, no zero-row entry."""
+    q, r = divmod(t * top_k, num_experts)
+    if q == 0:
+        return [(1, r)]
+    return [(m, n) for m, n in ((q + 1, r), (q, num_experts - r)) if n]
+
+
 def _moe_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
     d = cfg.d_model
     ff = cfg.moe_d_ff or cfg.d_ff
-    e = cfg.num_experts
-    # routing sparsity as per-expert effective batch: t*top_k active rows
-    # spread over E experts — never below one row per expert
-    m_e = max(1, round(t * routing_sparsity(cfg)))
-    out = [
-        ("moe.router", Gemm("router", t, d, e), 1, None),
-        ("moe.expert_gate", Gemm("expert_gate", m_e, d, ff), e, None),
-    ]
-    if cfg.gated_mlp:
-        out.append(("moe.expert_up", Gemm("expert_up", m_e, d, ff), e, None))
-    out.append(("moe.expert_down", Gemm("expert_down", m_e, ff, d), e, _POST_ACT_DENSITY))
+    out = [("moe.router", Gemm("router", t, d, cfg.num_experts), 1, None)]
+    for m_e, n_e in expert_rows(t, cfg.top_k, cfg.num_experts):
+        out.append(("moe.expert_gate", Gemm("expert_gate", m_e, d, ff), n_e, None))
+        if cfg.gated_mlp:
+            out.append(("moe.expert_up", Gemm("expert_up", m_e, d, ff), n_e, None))
+        out.append(
+            ("moe.expert_down", Gemm("expert_down", m_e, ff, d), n_e, _POST_ACT_DENSITY)
+        )
     if cfg.num_shared_experts:
         out += _dense_mlp_gemms(
             cfg, t, ff * cfg.num_shared_experts, prefix="moe.shared"
@@ -206,8 +249,10 @@ def _moe_gemms(cfg, t: int) -> list[tuple[str, Gemm, int, float | None]]:
     return out
 
 
+# mixer kind -> (cfg, t, regime) -> [(block, gemm, count, density)]
 _MIXERS = {
     "attn": _attn_gemms,
+    "mla": _mla_gemms,
     "mamba": _mamba_gemms,
     "mlstm": _mlstm_gemms,
     "slstm": _slstm_gemms,
@@ -219,39 +264,26 @@ def expand_arch(
 ) -> list[ServingGemm]:
     """Expand one serving step of ``cfg`` into its per-block GEMM job set.
 
-    Walks the stage pattern once per distinct (mixer, mlp) pair and scales
-    counts by how often the pair occurs across the whole stack (jamba's 7:1
-    mamba:attn ratio collapses to two mixer entries with counts 28 and 4),
-    then appends the LM head (one per codebook — musicgen's 4 parallel
-    heads).  Returns entries in deterministic walk order.
+    Emits the leading dense layers once (``cfg.first_k_dense`` of them),
+    then walks the stage pattern once per distinct (mixer, mlp) pair and
+    scales counts by how often the pair occurs across the periodic stack
+    (jamba's 7:1 mamba:attn ratio collapses to two mixer entries with
+    counts 28 and 4), then appends the LM head (one per codebook —
+    musicgen's 4 parallel heads).  Entries of one (block, shape) merge into
+    one with the summed count; order is the deterministic walk order.
     """
     t = regime_tokens(cfg, regime, batch, seq_len)
-    pair_counts = Counter(cfg.stage_pattern)
-    out: list[ServingGemm] = []
+    merged: dict[tuple, int] = {}
 
     def emit(entries, repeat: int):
         for block, gemm, count, density in entries:
-            out.append(
-                ServingGemm(
-                    gemm=gemm,
-                    block=block,
-                    regime=regime,
-                    count=count * repeat,
-                    input_density=density,
-                )
-            )
+            key = (block, gemm, density)
+            merged[key] = merged.get(key, 0) + count * repeat
 
-    # iterate pairs in first-occurrence order for deterministic output
-    seen: list[tuple] = []
-    for pair in cfg.stage_pattern:
-        if pair in seen:
-            continue
-        seen.append(pair)
-        mixer, mlp = pair
-        repeat = pair_counts[pair] * cfg.n_stages
+    def layer(mixer: str, mlp: str, repeat: int):
         if mixer not in _MIXERS:
             raise ValueError(f"{cfg.name}: unknown mixer kind {mixer!r}")
-        emit(_MIXERS[mixer](cfg, t), repeat)
+        emit(_MIXERS[mixer](cfg, t, regime), repeat)
         if mlp == "moe":
             emit(_moe_gemms(cfg, t), repeat)
         elif mlp == "dense":
@@ -261,11 +293,19 @@ def expand_arch(
         elif mlp != "none":
             raise ValueError(f"{cfg.name}: unknown mlp kind {mlp!r}")
 
+    if cfg.first_k_dense:
+        layer(cfg.stage_pattern[0][0], "dense", cfg.first_k_dense)
+    pair_counts = Counter(cfg.stage_pattern)
+    for pair in pair_counts:  # first-occurrence order
+        layer(*pair, pair_counts[pair] * cfg.n_stages)
     emit(
         [("head.lm_head", Gemm("lm_head", t, cfg.d_model, cfg.vocab_size), 1, None)],
         cfg.num_codebooks,
     )
-    return validate_job_set(out)
+    return validate_job_set(
+        ServingGemm(gemm=g, block=b, regime=regime, count=n, input_density=dens)
+        for (b, g, dens), n in merged.items()
+    )
 
 
 def expand_shape(cfg, shape) -> list[ServingGemm]:

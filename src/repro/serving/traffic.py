@@ -37,7 +37,7 @@ import numpy as np
 
 from repro.core.workloads import Gemm
 from repro.serving.expand import ServingGemm, expand_arch
-from repro.tracing import traced
+from repro.tracing import span
 
 __all__ = [
     "TrafficModel",
@@ -240,16 +240,57 @@ class ServingJobSet:
         mask = np.asarray([r == regime for r in self.regimes], float)
         return np.asarray(self.weights) * mask
 
+    def family_shares(self) -> dict[str, float]:
+        """MAC share of each block family the job set holds, summing to 1."""
+        shares: dict[str, float] = {}
+        for g, w in zip(self.gemms, self.weights):
+            fam = _block_family(g.name.split(".", 1)[1])
+            shares[fam] = shares.get(fam, 0.0) + float(w)
+        return {f: shares[f] for f in _FAMILIES if f in shares}
 
-@traced("expand")
+
+# block-name prefix -> family, for the job set's MAC share by family
+_FAMILY_OF = {
+    "mla": "mla",
+    "attn": "attn",
+    "mamba": "mamba",
+    "mlstm": "xlstm",
+    "slstm": "xlstm",
+    "mlp": "dense",
+    "moe.router": "moe.routed",
+    "moe.expert": "moe.routed",
+    "moe.shared": "moe.shared",
+    "head": "head",
+}
+_FAMILIES = tuple(dict.fromkeys(_FAMILY_OF.values()))
+
+
+def _block_family(block: str) -> str:
+    """The family of an expander block name (``moe.expert_up`` -> ``moe.routed``)."""
+    for prefix, fam in _FAMILY_OF.items():
+        if block.startswith(prefix):
+            return fam
+    raise ValueError(f"block {block!r} belongs to no family")
+
+
 def weighted_gemms(cfg, tm: TrafficModel, *, arch_name: str | None = None) -> ServingJobSet:
     """Expand ``cfg`` under every traffic class and weight by MAC share.
 
     Identical (regime, block, m, k, n) shape classes across traffic classes
     merge into one entry whose MAC/s accumulates in deterministic class
     order — the numpy-oracle re-derivation in benchmarks/bench_serving.py
-    reproduces these weights bit-exactly.
+    reproduces these weights bit-exactly.  Runs inside the span
+    ``repro.expand``, which records the result's ``gemm_classes`` and its
+    MAC share per block family (``ServingJobSet.family_shares``).
     """
+    with span("expand") as ev:
+        js = _weighted_gemms(cfg, tm, arch_name)
+        if ev is not None:
+            ev.set_metadata(gemm_classes=len(js.gemms), **js.family_shares())
+    return js
+
+
+def _weighted_gemms(cfg, tm: TrafficModel, arch_name: str | None) -> ServingJobSet:
     classes = traffic_classes(tm)
     order: dict[tuple, int] = {}
     entries: list[ServingGemm] = []

@@ -1,6 +1,7 @@
 """Per-architecture smoke tests: reduced config, one forward + train step +
-decode step on CPU, asserting output shapes and finiteness (no NaNs); plus
-full-config analytic parameter counts against the published model sizes."""
+decode step on CPU, asserting output shapes and finiteness (no NaNs), for
+every arch the LM stack builds (``model.builds``); plus full-config analytic
+parameter counts against the published model sizes, for every arch."""
 
 import jax
 import jax.numpy as jnp
@@ -21,12 +22,17 @@ EXPECTED_B = {
     "qwen3_8b": (8.2, 0.6),
     "llama4_maverick_400b": (400, 15),
     "mixtral_8x7b": (46.7, 2),
+    "deepseek_v3": (671, 10),  # without the MTP module
 }
 
 ACTIVE_B = {  # active (FLOP-bearing) params for the MoE archs
     "llama4_maverick_400b": (17, 3),
     "mixtral_8x7b": (12.9, 1.5),
+    "deepseek_v3": (37, 1.5),
 }
+
+# archs whose mixers and layer stack the LM stack builds
+BUILT = [a for a in ARCH_IDS if model.builds(get_arch(a))]
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -46,7 +52,7 @@ def _tokens(cfg, key, b, s):
     return jax.random.randint(key, shape, 0, cfg.vocab_size, dtype=jnp.int32)
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", BUILT)
 def test_reduced_forward_and_train_step(arch):
     cfg = get_arch(arch).reduced()
     key = jax.random.PRNGKey(0)
@@ -77,7 +83,7 @@ def test_reduced_forward_and_train_step(arch):
     assert bool(jnp.isfinite(gnorm)) and float(gnorm) > 0
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", BUILT)
 def test_reduced_decode_step(arch):
     cfg = get_arch(arch).reduced()
     key = jax.random.PRNGKey(1)
@@ -96,15 +102,26 @@ def test_reduced_decode_step(arch):
 
 
 def test_cell_matrix_counts():
-    """33 runnable cells: 10 archs x 4 shapes - 7 long_500k skips."""
+    """36 runnable cells: 11 archs x 4 shapes - 8 long_500k skips."""
     cells = all_cells()
-    assert len(cells) == 33
+    assert len(cells) == 36
     skipped = [
         a for a in ARCH_IDS if not applicable(get_arch(a), SHAPES["long_500k"])
     ]
-    assert len(skipped) == 7
+    assert len(skipped) == 8
     for a in ("jamba_v01_52b", "xlstm_1p3b", "mixtral_8x7b"):
         assert (a, "long_500k") in cells
+
+
+def test_builds_covers_every_other_arch():
+    """Only latent attention and leading dense layers fall outside the LM
+    stack, and it refuses them rather than running a wrong model."""
+    assert set(ARCH_IDS) - set(BUILT) == {"deepseek_v3"}
+    cfg = get_arch("deepseek_v3").reduced()
+    params, _ = model.init_params(cfg, jax.random.PRNGKey(0))
+    assert params["lead"]["mlp"]["w_gate"].shape == (1, cfg.d_model, cfg.d_ff)
+    with pytest.raises(NotImplementedError, match="latent attention"):
+        model.forward(cfg, params, _tokens(cfg, jax.random.PRNGKey(1), 1, 4))
 
 
 def test_mixtral_window_bounds_cache():

@@ -96,6 +96,21 @@ def test_moe_effective_expert_batch(arch):
     assert router and all(j.gemm.m == t and j.gemm.n == cfg.num_experts for j in router)
 
 
+@pytest.mark.parametrize("arch", MOE_ARCHS)
+def test_routed_rows_equal_tokens_times_top_k(arch):
+    """The even split prices exactly t * top_k routed expert rows per MoE
+    layer, over at most E experts, at every token batch and in both regimes."""
+    cfg = get_arch(arch)
+    moe_layers = sum(mlp == "moe" for _, mlp in cfg.stage_pattern) * cfg.n_stages
+    for regime in ("prefill", "decode"):
+        for t in range(1, 513):
+            jobs = expand_arch(cfg, regime, t) if regime == "decode" else expand_arch(cfg, regime, 1, t)
+            gate = [j for j in jobs if j.block == "moe.expert_gate"]
+            assert sum(j.gemm.m * j.count for j in gate) == t * cfg.top_k * moe_layers
+            assert sum(j.count for j in gate) == min(cfg.num_experts, t * cfg.top_k) * moe_layers
+            assert max(j.gemm.m for j in gate) - min(j.gemm.m for j in gate) <= 1
+
+
 @pytest.mark.parametrize("shape_id", sorted(SHAPES))
 def test_registry_shape_cells_expand(shape_id):
     shape = SHAPES[shape_id]

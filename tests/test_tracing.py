@@ -219,3 +219,20 @@ def test_collect_span_only_when_dispatched(tmp_path, dispatched):
     assert ("repro.profile.collect" in names) == dispatched
     assert stats.cache_hits == (0 if dispatched else 1)
     assert names.count("repro.profile.key") == (2 if dispatched else 1)
+
+
+def test_expand_span_carries_job_set_census(tmp_path):
+    """``repro.expand`` records the job set's GEMM class count and its MAC
+    share per block family, as ``ServingJobSet.family_shares`` gives them."""
+    import jax
+
+    from repro.configs.registry import get_arch
+    from repro.serving import get_preset, weighted_gemms
+
+    with jax.profiler.trace(str(tmp_path)):
+        js = weighted_gemms(get_arch("deepseek_v3"), get_preset("decode_heavy"))
+    (stats,) = [st for _, _, n, _, st in _trace_events(tmp_path) if n == "repro.expand"]
+    shares = js.family_shares()
+    assert list(shares) == ["mla", "dense", "moe.routed", "moe.shared", "head"]
+    assert sum(shares.values()) == pytest.approx(1.0, abs=1e-12)
+    assert stats == {"gemm_classes": len(js.gemms), **shares}
