@@ -43,7 +43,7 @@ import numpy as np
 from repro.core.optimize import bus_invert_activity_arr
 from repro.layout.geometry import envelope_coeffs, get_layout
 from repro.layout.segments import DATA_NETS, SEGMENT_CLASS_SCHEMA, segment_class_coeffs
-from repro.tracing import traced
+from repro.tracing import span, traced
 
 __all__ = [
     "LoweredCoeffs",
@@ -472,7 +472,6 @@ CODING_SCHEMES = {
 }
 
 
-@traced("lower.grid_coding_effective")
 def grid_coding_effective(grid, a_v, xp=np):
     """Effective (coded) vertical activity per (workload, point), host f64.
 
@@ -481,24 +480,64 @@ def grid_coding_effective(grid, a_v, xp=np):
     This is the single host-side transform both the closed-form design
     engine and the layout/objective engines consume — coding is no longer
     re-derived inside each jitted program.
+
+    The closed form is elementwise in (activity, width), and both take few
+    values on a design grid (activities are gathered from a per-class
+    table, widths from a handful of bus layouts), so it runs once on the
+    distinct pairs of the coded points and is scattered back: bit-equal to
+    evaluating every element.  Runs inside the span
+    ``repro.lower.grid_coding_effective``, which records ``coded_elements``
+    (coded entries of the output) and ``coded_evaluated`` (pairs the closed
+    form ran on; 0 on a memo hit).
     """
-    a_v = np.asarray(a_v, np.float64)
-    bi = np.asarray(grid.bus_invert, bool)
-    if not bi.any():
-        return a_v + 0.0
-    # The closed-form coded activity iterates a fixed point per element —
-    # the single most expensive host transform on a warm fleet evaluation —
-    # so it is memoized under the same content-keyed cache as the lowerings.
-    key = "coded|" + _coding_key(grid, a_v)
-    hit = _cache_get(key)
-    if hit is not None:
-        return hit.host["a_v_eff"]
-    bits = np.asarray(grid.b_v_data, np.float64)
-    coded = bus_invert_activity_arr(a_v, bits, xp=np)
-    out = np.where(bi, coded, a_v)
-    out.flags.writeable = False  # cached: callers copy before mutating
-    _cache_put(key, LoweredTensors(key, {"a_v_eff": out}))
-    return out
+    with span("lower.grid_coding_effective") as ev:
+        a_v = np.asarray(a_v, np.float64)
+        bi = np.asarray(grid.bus_invert, bool)
+        if not bi.any():
+            return a_v + 0.0
+        # Memoized under the same content-keyed cache as the lowerings.
+        key = "coded|" + _coding_key(grid, a_v)
+        hit = _cache_get(key)
+        if hit is not None:
+            out, n_eval = hit.host["a_v_eff"], 0
+        else:
+            out, n_eval = _bus_invert_distinct(a_v, grid.b_v_data, bi)
+            out.flags.writeable = False  # cached: callers copy before mutating
+            _cache_put(key, LoweredTensors(key, {"a_v_eff": out}))
+        if ev is not None:
+            n_rows = out.size // out.shape[-1]
+            ev.set_metadata(
+                coded_elements=n_rows * int(bi.sum()), coded_evaluated=n_eval
+            )
+        return out
+
+
+def _bus_invert_distinct(a_v, b_v_data, bi):
+    """``np.where(bi, bus_invert_activity_arr(a_v, bits), a_v)`` evaluated
+    once per distinct (activity, width) pair of the coded points; returns
+    the output and the number of pairs evaluated."""
+    bits = np.asarray(b_v_data, np.float64)
+    out = np.array(np.broadcast_to(a_v, np.broadcast_shapes(a_v.shape, bi.shape)))
+    cols = np.flatnonzero(bi)
+    coded = out[..., cols]
+    widths, width_of = np.unique(bits[cols], return_inverse=True)
+    groups, uniq = [], []
+    for k in range(len(widths)):
+        sel = np.flatnonzero(width_of == k)
+        block = coded[..., sel]
+        u, inv = np.unique(block, return_inverse=True)
+        groups.append((sel, block.shape, inv.reshape(-1)))
+        uniq.append(u)
+    n_uniq = [len(u) for u in uniq]
+    vals = bus_invert_activity_arr(
+        np.concatenate(uniq), np.repeat(widths, n_uniq), xp=np
+    )
+    start = 0
+    for (sel, shape, inv), n in zip(groups, n_uniq):
+        coded[..., sel] = vals[start + inv].reshape(shape)
+        start += n
+    out[..., cols] = coded
+    return out, int(vals.size)
 
 
 def _coding_key(grid, a_v) -> str:

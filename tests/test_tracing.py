@@ -236,3 +236,27 @@ def test_expand_span_carries_job_set_census(tmp_path):
     assert list(shares) == ["mla", "dense", "moe.routed", "moe.shared", "head"]
     assert sum(shares.values()) == pytest.approx(1.0, abs=1e-12)
     assert stats == {"gemm_classes": len(js.gemms), **shares}
+
+
+def test_coding_lowering_counters_in_trace(tmp_path):
+    """``repro.lower.grid_coding_effective`` carries its two counters on the
+    profiler's clock: coded entries and the distinct pairs evaluated."""
+    import jax
+
+    from repro.core.design_space import DesignSpace
+    from repro.layout.coeffs import clear_coeff_cache, grid_coding_effective
+
+    grid = DesignSpace(
+        rows=(8, 16), cols=(8, 16), input_bits=(8,), dataflows=("WS", "OS"),
+        bus_invert=(False, True),
+    ).expand()
+    a_v = np.tile([0.25, 0.5], (3, grid.n_points // 2))
+    clear_coeff_cache()
+    with jax.profiler.trace(str(tmp_path)):
+        grid_coding_effective(grid, a_v)
+    (stats,) = [
+        st for _, _, n, _, st in _trace_events(tmp_path)
+        if n == "repro.lower.grid_coding_effective"
+    ]
+    assert stats["coded_elements"] == 3 * grid.n_points // 2
+    assert 0 < stats["coded_evaluated"] <= stats["coded_elements"]
