@@ -36,12 +36,8 @@ from repro.compile_cache import persistent_cache_disabled
 from repro.configs.registry import get_arch
 from repro.core.pipeline import run_profile_batch
 from repro.core.switching import clear_profile_cache, profile_gemm
-from repro.core.workloads import (
-    RESNET50_TABLE1,
-    conv_layer_job,
-    gemm_job,
-    gemms_for_arch,
-)
+from repro.core.workloads import RESNET50_TABLE1, conv_layer_job, gemm_job
+from repro.serving.expand import expand_arch
 
 LLM_ARCH = "qwen15_4b"
 
@@ -49,7 +45,9 @@ LLM_ARCH = "qwen15_4b"
 def _jobs(smoke: bool):
     layers = RESNET50_TABLE1[2:5] if smoke else RESNET50_TABLE1
     jobs = [conv_layer_job(layer, seed=i) for i, layer in enumerate(layers)]
-    gemms = gemms_for_arch(get_arch(LLM_ARCH), seq_len=64)
+    gemms = [
+        sg.gemm for sg in expand_arch(get_arch(LLM_ARCH), "prefill", batch=1, seq_len=64)
+    ]
     if smoke:
         gemms = gemms[:3]
     jobs += [

@@ -1,6 +1,7 @@
 """Beyond-paper: the asymmetric-floorplan optimization applied to the LLM
-era — per-architecture GEMM sets (all 10 assigned archs) streamed through an
-int8 128x128 inference array, with per-arch activity profiles and savings.
+era — per-architecture GEMM sets (every registry arch, expanded by
+``repro.serving.expand`` as one prefill step) streamed through an int8
+128x128 inference array, with per-arch activity profiles and savings.
 
 Every architecture's whole GEMM set is ONE batched pipeline call (a couple
 of fused device programs, content-deduped, cached); the per-arch calls
@@ -20,7 +21,8 @@ from repro.core.floorplan import (
     optimal_aspect_power,
 )
 from repro.core.switching import combine_profiles, profile_gemms
-from repro.core.workloads import gemm_job, gemms_for_arch
+from repro.core.workloads import gemm_job
+from repro.serving.expand import expand_arch
 
 configure_compile_cache()
 
@@ -35,7 +37,7 @@ print(f"{'arch':26s} {'#GEMMs':>6s} {'a_h':>6s} {'a_v':>6s} {'W/H*':>6s} {'int.s
 
 for seed_base, arch in enumerate(ARCH_IDS):
     cfg = get_arch(arch)
-    gemms = gemms_for_arch(cfg, seq_len=64, batch=1)
+    gemms = [sg.gemm for sg in expand_arch(cfg, "prefill", batch=1, seq_len=64)]
     # profile the distinct per-layer GEMMs, one batched call per arch
     jobs = [
         gemm_job(g, rows=ROWS, cols=COLS, bits=BITS, seed=100 * seed_base + i)

@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro.configs.params import count_params
+
 
 @dataclasses.dataclass(frozen=True)
 class HardwareModel:
@@ -104,9 +106,7 @@ def roofline(
 
 def model_flops_for(cfg, shape) -> float:
     """6ND (train) / 2ND (inference) with N = active FLOP-bearing params."""
-    from repro.models.model import count_params_analytic
-
-    n = count_params_analytic(cfg, active_only=True, exclude_embed=True)
+    n = count_params(cfg, active_only=True, exclude_embed=True)
     if shape.kind == "train":
         d = shape.global_batch * shape.seq_len
         return 6.0 * n * d

@@ -12,6 +12,8 @@ import dataclasses
 import importlib
 from typing import Literal
 
+from repro.configs.params import count_params
+
 MixerKind = Literal["attn", "mla", "mamba", "mlstm", "slstm"]
 MlpKind = Literal["dense", "moe", "none"]
 
@@ -120,6 +122,12 @@ class ArchConfig:
     def dt_rank(self) -> int:
         return self.mamba_dt_rank or max(self.d_model // 16, 8)
 
+    @property
+    def slstm_ff(self) -> int:
+        """Width of the sLSTM block's post-recurrence gated MLP (xLSTM PF
+        4/3), rounded to a multiple of 128."""
+        return max(128, int(round(self.xlstm_slstm_pf * self.d_model / 128)) * 128)
+
     def with_dtypes(self, param: str, compute: str) -> "ArchConfig":
         return dataclasses.replace(self, param_dtype=param, compute_dtype=compute)
 
@@ -159,15 +167,25 @@ class ArchConfig:
         )
 
     def param_count(self) -> int:
-        """Analytic total parameter count (embeddings + stages + head)."""
-        from repro.models.model import count_params_analytic
-
-        return count_params_analytic(self)
+        """Total parameter count (embeddings + stages + head), closed form."""
+        return count_params(self)
 
     def active_param_count(self) -> int:
-        from repro.models.model import count_params_analytic
+        return count_params(self, active_only=True)
 
-        return count_params_analytic(self, active_only=True)
+
+def token_shape(cfg: ArchConfig, batch: int, seq: int) -> tuple[int, ...]:
+    """Token-array shape for one step: (B, S) or (B, S, codebooks).
+
+    THE shape authority shared by the serving workload expansion
+    (``repro.serving.expand``) and the LM stack's batch specs
+    (``launch/specs.py``): decode is ``seq == 1``, so
+    ``token_shape(cfg, b, 1)`` is exactly the ``decode_batch_specs`` token
+    shape (regression-tested in tests/test_serving.py).
+    """
+    if cfg.num_codebooks > 1:
+        return (batch, seq, cfg.num_codebooks)
+    return (batch, seq)
 
 
 @dataclasses.dataclass(frozen=True)

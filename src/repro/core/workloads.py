@@ -5,9 +5,10 @@ Two sources:
      lowered conv -> im2col GEMM, with synthetic post-ReLU activations
      (density matched to typical ResNet50 layer sparsity) and zero-mean
      weights, quantized to int16 exactly as in Section IV.
-  2. Any framework model — ``gemms_for_arch`` extracts the per-layer GEMM set
-     (attention projections, FFN/experts, vocab) of an assigned architecture
-     so the floorplan optimizer can be run on LLM workloads (beyond-paper).
+  2. Any registry model config — ``repro.serving.expand.expand_arch`` walks
+     it into the GEMM set of one serving step (attention, latent attention,
+     Mamba, m/sLSTM, dense/MoE MLP, LM head), so the floorplan optimizer can
+     be run on LLM workloads (beyond-paper).
 """
 
 from __future__ import annotations
@@ -42,7 +43,6 @@ __all__ = [
     "measured_design_lane_activities",
     "partition_gemm",
     "design_pod_partition",
-    "gemms_for_arch",
 ]
 
 
@@ -817,42 +817,6 @@ def design_pod_partition(grid, layouts, gemms: Sequence[Gemm], weights=None):
         "trunk_words_per_mac": (w3 * h["trunk_words_per_mac"]).sum(axis=0),
         "spill_words_per_mac": (w3 * h["spill_words_per_mac"]).sum(axis=0),
     }
-
-
-def gemms_for_arch(cfg, seq_len: int, batch: int = 1) -> list[Gemm]:
-    """Per-token-batch GEMM set of one transformer layer + vocab projection.
-
-    ``cfg`` is a ``repro.configs.registry.ArchConfig``. M is tokens
-    (batch * seq), K/N the weight dims. MoE experts contribute their active
-    (top-k) share of tokens. Used by ``examples/sa_power_llm.py`` to run the
-    paper's floorplan optimization on LLM inference workloads.
-    """
-    tokens = seq_len * batch
-    d = cfg.d_model
-    head_dim = cfg.head_dim
-    gemms: list[Gemm] = [
-        Gemm("q_proj", tokens, d, cfg.num_heads * head_dim),
-        Gemm("k_proj", tokens, d, cfg.num_kv_heads * head_dim),
-        Gemm("v_proj", tokens, d, cfg.num_kv_heads * head_dim),
-        Gemm("o_proj", tokens, cfg.num_heads * head_dim, d),
-    ]
-    if cfg.num_experts > 1:
-        ff = cfg.d_ff
-        active_tokens = tokens * cfg.top_k
-        gemms += [
-            Gemm("moe_gate", tokens, d, cfg.num_experts),
-            Gemm("expert_up", active_tokens, d, ff),
-            Gemm("expert_gate", active_tokens, d, ff),
-            Gemm("expert_down", active_tokens, ff, d),
-        ]
-    elif cfg.d_ff > 0:
-        gemms += [
-            Gemm("ffn_up", tokens, d, cfg.d_ff),
-            Gemm("ffn_gate", tokens, d, cfg.d_ff),
-            Gemm("ffn_down", tokens, cfg.d_ff, d),
-        ]
-    gemms.append(Gemm("lm_head", tokens, d, cfg.vocab_size))
-    return gemms
 
 
 def total_macs(gemms: Sequence[Gemm]) -> int:

@@ -13,6 +13,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro.configs.registry import token_shape
 from repro.models import model
 from repro.optim import adamw
 
@@ -36,21 +37,6 @@ def eval_shape_with_axes(fn: Callable[[], tuple[Any, Any]]) -> tuple[Any, Any]:
 # ---------------------------------------------------------------------------
 # Batch specs
 # ---------------------------------------------------------------------------
-
-
-def token_shape(cfg, batch: int, seq: int) -> tuple[int, ...]:
-    """Token-array shape for one step: (B, S) or (B, S, codebooks).
-
-    THE shape authority shared by the dry-run specs below and the serving
-    workload expansion (``repro.serving.expand``): decode is ``seq == 1``,
-    so ``token_shape(cfg, b, 1)`` is exactly the ``decode_batch_specs``
-    token shape — one helper, no duplicated shape math (the historical
-    decode-shape drift between ``launch/`` and workload generators is
-    regression-tested in tests/test_serving.py).
-    """
-    if cfg.num_codebooks > 1:
-        return (batch, seq, cfg.num_codebooks)
-    return (batch, seq)
 
 
 def _token_spec(cfg, batch: int, seq: int) -> tuple[SDS, tuple]:

@@ -64,29 +64,6 @@ def attn_init(key, cfg, stack: int) -> tuple[dict, dict]:
     return p, a
 
 
-def mla_init(key, cfg, stack: int) -> tuple[dict, dict]:
-    """Multi-head latent attention weights (DeepSeek-V2 §2.1), for parameter
-    accounting: this stack has no MLA forward (``model.builds``)."""
-    d, h = cfg.d_model, cfg.num_heads
-    nope, rope, dv = cfg.head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    keys = jax.random.split(key, 5)
-    p, a = {}, {}
-    p["wq_a"], a["wq_a"] = dense_param(keys[0], (d, cfg.q_lora_rank), ("embed", None), stack=stack)
-    p["q_norm"], a["q_norm"] = ones_param((cfg.q_lora_rank,), (None,), stack=stack)
-    p["wq_b"], a["wq_b"] = dense_param(
-        keys[1], (cfg.q_lora_rank, h, nope + rope), (None, "heads", None), stack=stack
-    )
-    p["wkv_a"], a["wkv_a"] = dense_param(
-        keys[2], (d, cfg.kv_lora_rank + rope), ("embed", None), stack=stack
-    )
-    p["kv_norm"], a["kv_norm"] = ones_param((cfg.kv_lora_rank,), (None,), stack=stack)
-    p["wkv_b"], a["wkv_b"] = dense_param(
-        keys[3], (cfg.kv_lora_rank, h, nope + dv), (None, "heads", None), stack=stack
-    )
-    p["wo"], a["wo"] = dense_param(keys[4], (h, dv, d), ("heads", None, "embed"), stack=stack)
-    return p, a
-
-
 def _qkv(p, x, cfg, cos, sin):
     """Project + (bias) + (qk-norm) + rope. x: (B, S, D) -> q/k/v (B, H, S, hd)."""
     q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"].astype(x.dtype))

@@ -35,7 +35,6 @@ Axes = dict
 
 _MIXER_INIT = {
     "attn": blocks.attn_init,
-    "mla": blocks.mla_init,
     "mamba": ssm.mamba_init,
     "mlstm": xlstm.mlstm_init,
     "slstm": xlstm.slstm_init,
@@ -43,21 +42,22 @@ _MIXER_INIT = {
 
 
 def builds(cfg) -> bool:
-    """Whether forward, loss, cache and decode build ``cfg``: every mixer
-    but latent attention, and no leading dense layers. ``init_params``
-    (hence ``count_params_analytic``) covers every config."""
+    """Whether this stack builds ``cfg`` (parameters, forward, loss, cache
+    and decode): every mixer but latent attention, and no leading dense
+    layers. ``repro.configs.params`` counts every config's parameters."""
     return not cfg.first_k_dense and all(m != "mla" for m, _ in cfg.stage_pattern)
 
 
 def _require_builds(cfg):
     if not builds(cfg):
         raise NotImplementedError(
-            f"{cfg.name}: latent attention and leading dense layers have no "
-            "forward in this stack"
+            f"{cfg.name}: latent attention and leading dense layers are not "
+            "built by this stack (repro.configs.params counts their parameters)"
         )
 
 
 def init_params(cfg, key: jax.Array) -> tuple[Params, Axes]:
+    _require_builds(cfg)
     dtype = jnp.dtype(cfg.param_dtype)
     k_embed, k_head, k_stages = jax.random.split(key, 3)
     p: Params = {}
@@ -103,17 +103,6 @@ def init_params(cfg, key: jax.Array) -> tuple[Params, Axes]:
         stages_a[f"block{i}"] = ba
     p["stages"] = stages_p
     a["stages"] = stages_a
-
-    if cfg.first_k_dense:  # leading layers: the first mixer, a dense MLP
-        n = cfg.first_k_dense
-        lk = jax.random.split(jax.random.fold_in(k_stages, len(cfg.stage_pattern)), 2)
-        lp, la = {}, {}
-        lp["ln1"], la["ln1"] = ones_param((cfg.d_model,), ("embed",), stack=n)
-        lp["mixer"], la["mixer"] = _MIXER_INIT[cfg.stage_pattern[0][0]](lk[0], cfg, n)
-        lp["ln2"], la["ln2"] = ones_param((cfg.d_model,), ("embed",), stack=n)
-        lp["mlp"], la["mlp"] = blocks.mlp_init(lk[1], cfg, n)
-        p["lead"] = lp
-        a["lead"] = la
 
     if dtype != jnp.float32:
         p = jax.tree.map(
@@ -455,7 +444,7 @@ def prefill_with_cache(cfg, params, tokens, cache_seq_len: int | None = None):
 
 
 # ---------------------------------------------------------------------------
-# Parameter accounting (for MODEL_FLOPS in the roofline)
+# Parameter accounting (the reference for ``repro.configs.params``)
 # ---------------------------------------------------------------------------
 
 
@@ -479,10 +468,11 @@ def shapes_and_axes(cfg) -> tuple[Any, Any]:
 def count_params_analytic(
     cfg, active_only: bool = False, exclude_embed: bool = False
 ) -> int:
-    """Exact param count via eval_shape. ``active_only`` scales expert tables
-    by top_k/E (MoE active params); ``exclude_embed`` drops the input
-    embedding table (gather, not matmul) for 6ND MODEL_FLOPS accounting —
-    the LM head IS counted."""
+    """Exact param count of this stack's ``init_params``, via eval_shape.
+    ``active_only`` scales expert tables by top_k/E (MoE active params);
+    ``exclude_embed`` drops the input embedding table — the LM head IS
+    counted. The closed form ``repro.configs.params.count_params`` is the
+    count the rest of the repo reads; tests hold the two equal."""
     p_shapes, axes = shapes_and_axes(cfg)
     total = 0
     for leaf, ax in zip(

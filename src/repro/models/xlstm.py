@@ -210,8 +210,7 @@ def slstm_init(key, cfg, stack: int) -> tuple[dict, dict]:
         )
         p[f"b_{gate}"], a[f"b_{gate}"] = zeros_param((d,), ("inner",), stack=stack)
     p["out_norm"], a["out_norm"] = ones_param((d,), ("embed",), stack=stack)
-    # post-recurrence gated MLP (xLSTM block: PF 4/3), rounded to 128
-    ff = max(128, int(round(cfg.xlstm_slstm_pf * d / 128)) * 128)
+    ff = cfg.slstm_ff  # post-recurrence gated MLP
     p["w_ff_gate"], a["w_ff_gate"] = dense_param(keys[8], (d, ff), ("embed", "mlp"), stack=stack)
     p["w_ff_down"], a["w_ff_down"] = dense_param(keys[9], (ff, d), ("mlp", "embed"), stack=stack)
     return p, a

@@ -7,9 +7,9 @@ GEMM shapes one forward step executes under a serving regime:
 
   * ``prefill``: M = batch * seq_len tokens flow through every projection;
   * ``decode``:  M = the decode-step token count, derived from the SAME
-    ``launch.specs.token_shape`` helper the dry-run batch specs use (seq
-    axis == 1), so the serving expansion and ``decode_batch_specs`` can
-    never drift apart.
+    ``configs.registry.token_shape`` helper the LM stack's batch specs use
+    (seq axis == 1), so the serving expansion and ``decode_batch_specs``
+    can never drift apart.
 
 Leading dense layers (``cfg.first_k_dense``) are emitted once, with their
 count, before the periodic walk of ``stage_pattern``; each runs the
@@ -34,8 +34,7 @@ priced routed rows equal t*top_k exactly, and the router and any shared
 experts run at the full token batch.  Attention score/context products
 (QK^T, PV, and their latent forms) are cache-shaped dynamic-by-dynamic
 products served by the flash-attention kernel, not stationary-weight GEMMs,
-and are out of scope here — same contract as
-``core.workloads.gemms_for_arch``.
+and are out of scope here.
 
 Every emitted ``ServingGemm`` carries a ``count`` multiplicity (layers x
 heads x experts ...) so identical shapes collapse to one entry, and an
@@ -49,8 +48,8 @@ import dataclasses
 from collections import Counter
 from typing import Sequence
 
+from repro.configs.registry import token_shape
 from repro.core.workloads import Gemm
-from repro.launch.specs import token_shape
 
 __all__ = [
     "ServingGemm",
@@ -199,8 +198,7 @@ def _slstm_gemms(cfg, t: int, regime: str) -> list[tuple[str, Gemm, int, float |
     d = cfg.d_model
     h = cfg.num_heads
     dh = d // h
-    # xLSTM sLSTM post-recurrence gated MLP width (models/xlstm.py)
-    ff = max(128, int(round(cfg.xlstm_slstm_pf * d / 128)) * 128)
+    ff = cfg.slstm_ff  # post-recurrence gated MLP
     return [
         # four gate input projections z/i/f/o, each (t, d) @ (d, d)
         ("slstm.w_gates", Gemm("w_gates", t, d, d), 4, None),

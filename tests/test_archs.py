@@ -1,12 +1,14 @@
 """Per-architecture smoke tests: reduced config, one forward + train step +
 decode step on CPU, asserting output shapes and finiteness (no NaNs), for
-every arch the LM stack builds (``model.builds``); plus full-config analytic
-parameter counts against the published model sizes, for every arch."""
+every arch the LM stack builds (``model.builds``); plus full-config
+closed-form parameter counts against the published model sizes, for every
+arch, and against the LM stack's own count where it builds the arch."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs.params import count_params
 from repro.configs.registry import ARCH_IDS, SHAPES, all_cells, applicable, get_arch
 from repro.models import model
 
@@ -38,13 +40,25 @@ BUILT = [a for a in ARCH_IDS if model.builds(get_arch(a))]
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_full_config_param_count(arch):
     cfg = get_arch(arch)
-    n = model.count_params_analytic(cfg) / 1e9
+    n = cfg.param_count() / 1e9
     want, tol = EXPECTED_B[arch]
     assert abs(n - want) <= tol, f"{arch}: {n:.2f}B vs {want}B"
     if arch in ACTIVE_B:
-        na = model.count_params_analytic(cfg, active_only=True) / 1e9
+        na = cfg.active_param_count() / 1e9
         want_a, tol_a = ACTIVE_B[arch]
         assert abs(na - want_a) <= tol_a
+
+
+@pytest.mark.parametrize("arch", BUILT)
+def test_closed_form_matches_init_params(arch):
+    """The closed form counts exactly the weights ``init_params`` allocates,
+    in every (active_only, exclude_embed) accounting."""
+    cfg = get_arch(arch)
+    for a in (False, True):
+        for e in (False, True):
+            assert count_params(cfg, active_only=a, exclude_embed=e) == (
+                model.count_params_analytic(cfg, active_only=a, exclude_embed=e)
+            ), (a, e)
 
 
 def _tokens(cfg, key, b, s):
@@ -115,13 +129,14 @@ def test_cell_matrix_counts():
 
 def test_builds_covers_every_other_arch():
     """Only latent attention and leading dense layers fall outside the LM
-    stack, and it refuses them rather than running a wrong model."""
+    stack, and it refuses them, parameters and forward alike, rather than
+    building a wrong model."""
     assert set(ARCH_IDS) - set(BUILT) == {"deepseek_v3"}
     cfg = get_arch("deepseek_v3").reduced()
-    params, _ = model.init_params(cfg, jax.random.PRNGKey(0))
-    assert params["lead"]["mlp"]["w_gate"].shape == (1, cfg.d_model, cfg.d_ff)
     with pytest.raises(NotImplementedError, match="latent attention"):
-        model.forward(cfg, params, _tokens(cfg, jax.random.PRNGKey(1), 1, 4))
+        model.init_params(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(NotImplementedError, match="latent attention"):
+        model.forward(cfg, {}, _tokens(cfg, jax.random.PRNGKey(1), 1, 4))
 
 
 def test_mixtral_window_bounds_cache():

@@ -87,12 +87,10 @@ def test_decode_macs_per_token():
 
 
 def test_closed_form_counts_match_the_registry():
-    from repro.models import model
-
     cfg = get_arch("deepseek_v3")
     cf = ref.closed_form(ref.PUBLISHED)
-    assert model.count_params_analytic(cfg) == cf["params"]
-    assert model.count_params_analytic(cfg, active_only=True) == cf["active_params"]
+    assert cfg.param_count() == cf["params"]
+    assert cfg.active_param_count() == cf["active_params"]
     assert round(cf["params"] / 1e9, 1) == 671.0
     assert round(cf["active_params"] / 1e9, 2) == 37.55
 

@@ -19,12 +19,8 @@ from repro.core.floorplan import (
 )
 from repro.core.quant import dequantize, quantize_symmetric
 from repro.core.switching import combine_profiles
-from repro.core.workloads import (
-    RESNET50_TABLE1,
-    conv_to_gemm,
-    gemms_for_arch,
-    profile_conv_layer,
-)
+from repro.core.workloads import RESNET50_TABLE1, conv_to_gemm, profile_conv_layer
+from repro.serving.expand import expand_arch
 
 GEOM = SystolicArrayGeometry.paper_32x32()
 PAPER_ACT = BusActivity.paper_resnet50()
@@ -99,11 +95,13 @@ def test_llm_gemm_extraction():
     """Beyond-paper: the SA analysis consumes LLM layer GEMMs too."""
     from repro.configs.registry import get_arch
 
-    gemms = gemms_for_arch(get_arch("yi_6b"), seq_len=128, batch=1)
-    names = {g.name for g in gemms}
-    assert {"q_proj", "k_proj", "o_proj", "ffn_up", "lm_head"} <= names
-    q = next(g for g in gemms if g.name == "q_proj")
+    jobs = expand_arch(get_arch("yi_6b"), "prefill", batch=1, seq_len=128)
+    blocks = {j.block for j in jobs}
+    want = {"attn.q_proj", "attn.k_proj", "attn.o_proj", "mlp.w_up", "head.lm_head"}
+    assert want <= blocks
+    q = next(j.gemm for j in jobs if j.block == "attn.q_proj")
     assert (q.m, q.k, q.n) == (128, 4096, 4096)
-    moe = gemms_for_arch(get_arch("mixtral_8x7b"), seq_len=128, batch=1)
-    eu = next(g for g in moe if g.name == "expert_up")
-    assert eu.m == 128 * 2  # top-2 active tokens
+    mixtral = get_arch("mixtral_8x7b")
+    moe = expand_arch(mixtral, "prefill", batch=1, seq_len=128)
+    rows = sum(j.gemm.m * j.count for j in moe if j.block == "moe.expert_up")
+    assert rows == 128 * 2 * mixtral.n_layers  # top-2 routed rows in every layer

@@ -1,12 +1,13 @@
 """Serving-traffic subsystem: registry expansion, traffic model, J/token.
 
 Covers the three layers of ``repro.serving`` plus the shared decode-shape
-authority in ``launch.specs``:
+authority in ``configs.registry``:
 
   * every registry config expands to a non-empty, positive-shape GEMM job
     set in both regimes, with MoE routing sparsity in (0, 1];
   * decode shapes can no longer drift: ``decode_batch_specs`` and the
-    serving expansion both derive M from ``launch.specs.token_shape``;
+    serving expansion both derive M from ``configs.registry.token_shape``;
+  * importing the explorer loads none of the LM training stack;
   * the seeded traffic model is bit-deterministic, MAC-share weights sum
     to 1, and sweeping the prefill:decode ratio MOVES the design optimum
     (regression-pinned);
@@ -15,11 +16,15 @@ authority in ``launch.specs``:
 """
 
 import dataclasses
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
 
-from repro.configs.registry import ARCH_IDS, SHAPES, get_arch
+from repro.configs.registry import ARCH_IDS, SHAPES, get_arch, token_shape
 from repro.core.design_space import DesignSpace
 from repro.core.objective import evaluate_fleet_objective
 from repro.core.workloads import (
@@ -27,7 +32,7 @@ from repro.core.workloads import (
     gemm_profile_seed,
     measured_design_gemm_activities,
 )
-from repro.launch.specs import decode_batch_specs, token_shape
+from repro.launch.specs import decode_batch_specs
 from repro.serving import (
     PRESETS,
     ServingGemm,
@@ -136,6 +141,27 @@ def test_expand_contract_errors():
 # ---------------------------------------------------------------------------
 # Decode-shape drift: launch specs and serving expansion share one authority
 # ---------------------------------------------------------------------------
+
+
+LM_STACK = {
+    f"repro.{p}"
+    for p in ("models", "launch", "optim", "parallel", "data", "checkpoint", "analysis")
+}
+
+
+def test_codesign_imports_no_lm_stack():
+    """The explorer's import closure stops at configs/: importing
+    ``repro.serving.codesign`` loads no module of the LM training stack."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    path = [src, os.environ.get("PYTHONPATH", "")]
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(filter(None, path)))
+    out = subprocess.run(
+        [sys.executable, "-c", "import sys, repro.serving.codesign; print(*sys.modules)"],
+        capture_output=True, text=True, env=env, timeout=120, check=True,
+    ).stdout.split()
+    assert "repro.serving.codesign" in out
+    loaded = [m for m in out if ".".join(m.split(".")[:2]) in LM_STACK]
+    assert not loaded, loaded
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
